@@ -27,7 +27,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from benchmarks.alloc_bench import _merge_out
 from repro.fed.fleet import _fleet_solve, build_fleet_problems
@@ -45,7 +44,7 @@ def solve_only_row(f: int, k: int = 8, *, mesh=None, scheme: str = "kkt_sai",
     bp = build_fleet_problems(f, k, T=T, total_samples=total_samples,
                               seed=seed)
     axes = fleet_partition_axes(f, mesh)
-    with enable_x64():
+    with jax.enable_x64(True):
         args = (
             jnp.asarray(bp.c2, jnp.float64), jnp.asarray(bp.c1, jnp.float64),
             jnp.asarray(bp.c0, jnp.float64), jnp.asarray(bp.T, jnp.float64),

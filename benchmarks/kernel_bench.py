@@ -94,26 +94,34 @@ def _megakernel_case(k: int, n: int, tau_hi: int, layers, seed: int):
     return disp, x, y, m, tau, w
 
 
-def _megakernel_parity(layers=(16, 16, 4), seed=0) -> None:
-    """Fixed-seed gate: the Pallas megakernel (interpret) must match the
-    unfused local_train_stacked + fed_agg composition BITWISE before any
-    timing row is merged. Raises on the first differing bit."""
+def _megakernel_parity(layers=(16, 16, 4), seed=0) -> str:
+    """Fixed-seed gate: the Pallas megakernel (compiled on a TPU,
+    interpreted elsewhere) must match the unfused local_train_stacked +
+    fed_agg composition, run at highest matmul precision, to the kernel's
+    stated f32 tolerance before any timing row is merged. Raises on the
+    first leaf outside it; returns the tolerance it held."""
     import numpy as np
 
+    from repro.kernels import train_step
     from repro.models import mlp
 
+    on_tpu = jax.default_backend() == "tpu"
+    rtol, atol = ((train_step.CHIP_RTOL, train_step.CHIP_ATOL) if on_tpu
+                  else (train_step.F32_RTOL, train_step.F32_ATOL))
     disp, x, y, m, tau, w = _megakernel_case(4, 16, 3, list(layers), seed)
     lr = jnp.float32(0.05)
-    want, _ = ops.train_agg_step(disp, x, y, m, tau, w, lr, loss_fn=mlp.loss,
-                                 max_tau=int(tau.max()))
+    with jax.default_matmul_precision("highest"):
+        want, _ = ops.train_agg_step(disp, x, y, m, tau, w, lr,
+                                     loss_fn=mlp.loss, max_tau=int(tau.max()))
     got, _ = ops.train_agg_step(disp, x, y, m, tau, w, lr, loss_fn=mlp.loss,
-                                use_pallas=True, interpret=True)
+                                use_pallas=True, interpret=not on_tpu)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
-        if not np.array_equal(np.asarray(a), np.asarray(b)):
-            raise AssertionError(
-                "megakernel parity gate failed: fused != unfused bitwise"
-            )
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=rtol, atol=atol,
+            err_msg="megakernel parity gate failed: fused != unfused",
+        )
+    return f"rtol={rtol:g} atol={atol:g}"
 
 
 def megakernel_rows(quick: bool = True):
@@ -152,13 +160,14 @@ def megakernel_rows(quick: bool = True):
 
 
 def megakernel_main(quick: bool = False):
-    """`--only megakernel`: bitwise parity gate first, then the per-step
-    fused-vs-unfused table merged under BENCH_alloc.json[megakernel]."""
+    """`--only megakernel`: the tolerance parity gate first, then the
+    per-step fused-vs-unfused table merged under
+    BENCH_alloc.json[megakernel]."""
     from benchmarks.alloc_bench import _merge_out
 
-    _megakernel_parity()
-    print("parity: fused == unfused bitwise on fixed seed", flush=True)
+    tol = _megakernel_parity()
+    print(f"parity: fused == unfused within {tol} on fixed seed", flush=True)
     rows = megakernel_rows(quick=quick)
     for r in rows:
         print(f"{r['case']},{r['path']},{r['us_per_step']}")
-    _merge_out("megakernel", {"parity_bitwise": True, "rows": rows})
+    _merge_out("megakernel", {"parity_tolerance": tol, "rows": rows})

@@ -109,6 +109,10 @@ def main() -> None:
 
     import json
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
+
     for name, fn in SECTIONS:
         if args.only and args.only not in name:
             continue

@@ -44,7 +44,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.allocation import Allocation, AllocationProblem
 from repro.core.time_model import TimeModel
@@ -746,7 +745,7 @@ def solve_kkt_batched(
     bp = _as_batched(problems)
     fdt = np.float64 if x64 else np.float32
     idt = np.int64 if x64 else np.int32
-    ctx = enable_x64() if x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if x64 else contextlib.nullcontext()
     with ctx:
         out = _solve_kkt_batched_impl(
             jnp.asarray(bp.c2, fdt), jnp.asarray(bp.c1, fdt),
@@ -925,7 +924,7 @@ def batched_policy(
       ``feasible``: (B,) bool, False where even tau = 0 cannot absorb the
       budget (outputs in such rows are neutralized, not meaningful).
 
-    Run under ``enable_x64`` with f64 inputs to reproduce the NumPy
+    Run under ``jax.enable_x64`` with f64 inputs to reproduce the NumPy
     solvers decision-for-decision; f32 inputs give the device-resident
     fast path. The returned callable is cached per option set so jit
     caches keyed on it stay warm."""
@@ -959,7 +958,7 @@ def solve_eta_batched(problems, *, x64: bool = True) -> BatchedAllocation:
     bp = _as_batched(problems)
     fdt = np.float64 if x64 else np.float32
     idt = np.int64 if x64 else np.int32
-    ctx = enable_x64() if x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if x64 else contextlib.nullcontext()
     with ctx:
         tau, d, ok = _solve_eta_batched_impl(
             jnp.asarray(bp.c2, fdt), jnp.asarray(bp.c1, fdt),
@@ -1003,7 +1002,7 @@ def solve_energy_batched(
     e2, e1, e0, eb = bp.energy_rows()
     fdt = np.float64 if x64 else np.float32
     idt = np.int64 if x64 else np.int32
-    ctx = enable_x64() if x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if x64 else contextlib.nullcontext()
     with ctx:
         out = _solve_energy_batched_impl(
             jnp.asarray(bp.c2, fdt), jnp.asarray(bp.c1, fdt),
@@ -1038,7 +1037,7 @@ def apply_active_mask(total_i, d_lo, d_hi, valid, active):
     ``[sum d_lo, sum d_hi]`` — a thinned fleet serves what it can absorb
     instead of going infeasible; an all-offline fleet degrades to a zero
     budget.  Elementwise ``jnp`` only, so it is usable traced or on host
-    (run under ``enable_x64`` when exact integer budgets matter).
+    (run under ``jax.enable_x64`` when exact integer budgets matter).
 
     Returns ``(total, d_lo, d_hi, valid)`` with the same shapes/dtypes
     as the inputs.

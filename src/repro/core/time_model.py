@@ -204,15 +204,14 @@ class CapacityDrift:
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Drifted (c2, c1, c0) float64 numpy arrays of shape (C, K); row c
         is the fleet's true capacity during global cycle c. Runs under
-        ``enable_x64`` so the factors match the traced in-scan
+        ``jax.enable_x64`` so the factors match the traced in-scan
         ``factors_at`` consumers as closely as the compiler allows (within
         1 f32 ULP; see class docstring)."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         k = tm.num_learners
-        with enable_x64():
+        with jax.enable_x64(True):
             clock, rate = jax.vmap(lambda c: self.factors_at(c, k))(
                 jnp.arange(cycles)
             )
@@ -337,20 +336,20 @@ class QueueDrift:
         only AFTER the feasible prefix ran — the same contract as the
         fused scan's in-scan guard).
 
-        The factor math runs under ``enable_x64`` (entered per cycle so
+        The factor math runs under ``jax.enable_x64`` (entered per cycle so
         the flag never leaks into consumer code between yields) with
         f32-pinned draws, exactly like ``CapacityDrift.coefficient_path``,
         so the rows match the fused scan's in-scan ``factors_at``
         consumers (bitwise for the queue coupling; 1 f32 ULP when a
         ``base`` drift composes its pow). Raises whatever ``solve``
         raises (e.g. infeasibility) at the first offending cycle."""
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         k = tm.num_learners
         state = None
         for c in range(cycles):
-            with enable_x64():
+            with jax.enable_x64(True):
                 if state is None:
                     state = self.state_init(k)
                 clock, rate = self.factors_at(c, k, state)

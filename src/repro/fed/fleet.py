@@ -6,7 +6,7 @@ fleet tensors — an ``(F, K)`` ``BatchedProblems`` struct for the allocation
 problems/capacities, ``(F, K, d_cap, feat)`` staged sample tensors, and a
 params-per-fleet pytree with a leading F axis — laid out over a mesh from
 ``launch.mesh`` with the ``sharding.rules.FLEET_RULES`` logical axis. Each
-global round is ONE jitted XLA program wrapped in ``compat.shard_map``:
+global round is ONE jitted XLA program wrapped in ``jax.shard_map``:
 
   1. every fleet runs its paper-scheme cycle — masked ``local_train`` to
      the fleet-wide max tau, vmapped over the local fleet shard, then the
@@ -34,11 +34,11 @@ record. Fleet f's partitioner seed is drawn from the engine rng in fleet
 order, so fleet 0 consumes the same ``(seed, draw-index)``-keyed shard
 draws the orchestrator's partitioner does.
 
-Scale: ``host_mesh()`` gives the (2, 4) ``"test"`` mesh under
+Scale: ``host_mesh()`` spans every device: the (2, 4) mesh under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the fleet-scale CI
-step) and the 1-device ``"cpu"`` mesh elsewhere; ``benchmarks/fleet_scale``
-drives 10^4 trained and 10^6 solved learners per virtual-time unit through
-the same two programs.
+step), (2, 2) on a four-chip TPU host, one device elsewhere;
+``benchmarks/fleet_scale`` drives 10^4 trained and 10^6 solved learners per
+virtual-time unit through the same two programs.
 """
 
 from __future__ import annotations
@@ -50,10 +50,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.solver_batched import (
     BatchedProblems,
     TRACED_POLICIES,
@@ -184,7 +182,7 @@ def _fleet_solve(c2, c1, c0, T, total, d_lo, d_hi, valid, sampled, *en,
                  scheme: str, mesh, fleet_axes):
     """ONE ``batched_policy`` call for every fleet's (tau, d), sharded over
     the fleet axis under ``shard_map``; sampled-out fleets get the padded
-    -slot projection and solve to zeros. Run under ``enable_x64`` with f64
+    -slot projection and solve to zeros. Run under ``jax.enable_x64`` with f64
     rows for exact integer allocations. Energy-aware schemes take four
     trailing (F, K) rows — ``(e2, e1, e0, e_budget)`` — sharded like the
     other per-learner tensors."""
@@ -200,11 +198,11 @@ def _fleet_solve(c2, c1, c0, T, total, d_lo, d_hi, valid, sampled, *en,
 
     row = _fleet_spec(fleet_axes, extra=1)
     vec = _fleet_spec(fleet_axes)
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(row, row, row, vec, vec, row, row, row, vec)
         + (row,) * len(en),
-        out_specs=(row, row, vec),
+        out_specs=(row, row, vec), check_vma=False,
     )(c2, c1, c0, T, total, d_lo, d_hi, valid, sampled, *en)
 
 
@@ -222,7 +220,7 @@ def _fleet_round(g, fleet_params, x, y, m, tau, d, base_w, sampled, mix, lr,
     """One global round as one XLA program (see module docstring): vmapped
     per-fleet train+aggregate, psum-normalized two-tier merge of the
     sampled fleets, and the next dispatch's sampling-masked policy solve.
-    Must run under ``enable_x64`` (f64 solve/weight math, f32 training).
+    Must run under ``jax.enable_x64`` (f64 solve/weight math, f32 training).
 
     Returns ``(new_global, new_fleet_params, tau', d', feasible, acc)``.
     """
@@ -236,6 +234,12 @@ def _fleet_round(g, fleet_params, x, y, m, tau, d, base_w, sampled, mix, lr,
              ex, ey, *en):
         # -- tier 1: each fleet trains its K learners and aggregates ------
         def fleet_step(fp, xf, yf, mf, tf, df):
+            # a padded fleet (d = 0 everywhere) has no weights to normalize
+            # (0/0): it keeps its model instead of turning it into NaN,
+            # which the merge's zero weight would still carry (0 * NaN)
+            idle = df.sum() == 0
+            keep = lambda new: jax.tree_util.tree_map(
+                lambda n, o: jnp.where(idle, o, n), new, fp)
             w = _weights_traced(tf, df, aggregation=aggregation, gamma=gamma)
             if use_pallas:
                 from repro.kernels import ops
@@ -249,13 +253,13 @@ def _fleet_round(g, fleet_params, x, y, m, tau, d, base_w, sampled, mix, lr,
                     disp, xf, yf, mf, tf, w, lr, loss_fn=loss_fn,
                     max_tau=max_tau, use_pallas=True, interpret=interpret,
                 )
-                return new
+                return keep(new)
             locals_ = local_train(
                 fp, xf, yf, mf, tf, lr, max_tau=max_tau, loss_fn=loss_fn
             )
-            return jax.tree_util.tree_map(
+            return keep(jax.tree_util.tree_map(
                 functools.partial(_wsum, w=w), locals_
-            )
+            ))
 
         fleet_new = jax.vmap(fleet_step)(fleet_params, x, y, m, tau, d)
 
@@ -314,8 +318,9 @@ def _fleet_round(g, fleet_params, x, y, m, tau, d, base_w, sampled, mix, lr,
         rep, rep,                                         # eval batch
     ) + (row,) * len(en)                                  # energy rows
     out_specs = (g_specs, fp_specs, row, row, vec, rep)
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )(g, fleet_params, x, y, m, tau, d, base_w, sampled, mix, lr, gamma,
       c2, c1, c0, T, total, d_lo, d_hi, valid, ex, ey, *en)
 
@@ -325,11 +330,10 @@ class FleetEngine:
 
     ``problems`` is the (F, K) ``BatchedProblems`` population (build one
     with ``build_fleet_problems``). ``mesh`` defaults to ``host_mesh()``
-    — the 8-fake-device ``"test"`` mesh when the process has one, else
-    the 1-device ``"cpu"`` mesh. F is padded up to a multiple of the mesh
-    device count with all-invalid fleets (never sampled, zero weight, zero
-    work: the ``BatchedProblems`` padded-slot semantics lifted one axis
-    up) so the fleet dim always splits evenly."""
+    — a data×model mesh over every device the process has. F is padded up
+    to a multiple of the mesh device count with all-invalid fleets (never
+    sampled, zero weight, zero work: the ``BatchedProblems`` padded-slot
+    semantics lifted one axis up) so the fleet dim always splits evenly."""
 
     def __init__(self, cfg: FleetConfig, problems: BatchedProblems, loss_fn,
                  init_params, *, seed: int = 0, mesh=None):
@@ -411,7 +415,7 @@ class FleetEngine:
     def _solve(self, sampled: np.ndarray):
         """(tau, d) int64 host arrays for the sampled fleets (zeros in the
         rest) — one sharded batched_policy call."""
-        with enable_x64():
+        with jax.enable_x64(True):
             tau, d, feas = _fleet_solve(
                 *self._solve_args(), jnp.asarray(sampled, bool),
                 *self._energy_args(),
@@ -453,7 +457,7 @@ class FleetEngine:
         f_pad, k = np.asarray(self.problems.c2).shape
         axes = fleet_partition_axes(s * f_pad, self.mesh)
         en = self._energy_args()
-        with enable_x64():
+        with jax.enable_x64(True):
             w = cross_model_weights(
                 jnp.asarray(deficits), policy=split, share_floor=share_floor
             )
@@ -568,7 +572,7 @@ class FleetEngine:
             )
             n_f = self.d.sum(axis=1).astype(np.float64)
             base_w = np.where(self._real, n_f * phi, 0.0)
-            with enable_x64():
+            with jax.enable_x64(True):
                 (g, fp, tau_n, d_n, feas, acc) = _fleet_round(
                     self.global_params, self.fleet_params,
                     jnp.asarray(x), jnp.asarray(y), jnp.asarray(m),

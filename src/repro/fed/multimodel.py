@@ -64,7 +64,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (
     AllocationProblem,
@@ -158,7 +157,7 @@ def solve_multimodel_rows(
     deficit-driven split computed inside the traced
     ``multimodel_policy``, and the whole thing solved as ONE compiled
     ``batched_policy`` call. Operand construction mirrors
-    ``solve_policy_row`` exactly (f64 under ``enable_x64``, the same
+    ``solve_policy_row`` exactly (f64 under ``jax.enable_x64``, the same
     ``policy_problem_args`` / ``policy_energy_args`` row builders), so at
     S = 1 — where the traced policy is a static pass-through — the solve
     is the single-model solve on identical operands.
@@ -201,7 +200,7 @@ def solve_multimodel_rows(
             z = np.zeros((s, k), np.int64)
             return z, z.copy(), np.full(s, 1.0 / s)
     policy = _jitted_multimodel(scheme, split, float(share_floor))
-    with enable_x64():
+    with jax.enable_x64(True):
         deficits_j = jnp.asarray(np.asarray(deficits, np.float64))
         total_j, lo_j, hi_j, valid_j = (
             jnp.asarray(total1), jnp.asarray(lo1),
@@ -448,7 +447,7 @@ class MultiModelEngine:
         state = drift.state_init(k)
         for c in range(nblocks):
             mask = np.asarray(drift.online_at(c, k, state))
-            with enable_x64():
+            with jax.enable_x64(True):
                 clock, rate = drift.factors_at(c, k, state)
                 clock = np.asarray(clock, np.float64)
                 rate = np.asarray(rate, np.float64)

@@ -44,7 +44,7 @@ partitioner's rng consumption depends only on the constant per-cycle
 total) and split by the traced d inside the scan, so for the same seed the
 tau/d history and the per-learner shard contents match the eager
 reallocation path exactly (allocation math runs in f64 under
-``enable_x64``; training stays f32).
+``jax.enable_x64``; training stays f32).
 """
 
 from __future__ import annotations
@@ -57,7 +57,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (
     Allocation,
@@ -315,7 +314,7 @@ def solve_policy_row(scheme: str, c2r, c1r, c0r, prob: AllocationProblem,
                      *, label: str, active=None, e_budget=None
                      ) -> tuple[np.ndarray, np.ndarray]:
     """One fleet's (tau, d) on a single (K,) capacity row through the
-    jitted traced policy, f64 under ``enable_x64`` — THE single-row solve
+    jitted traced policy, f64 under ``jax.enable_x64`` — THE single-row solve
     shared by the orchestrator's eager per-cycle re-solve and the async
     engine's per-block allocation (the barrier-equivalence guarantee
     depends on both paths using this exact code). Raises ValueError with
@@ -351,7 +350,7 @@ def solve_policy_row(scheme: str, c2r, c1r, c0r, prob: AllocationProblem,
         if not act.any():
             z = np.zeros(k, np.int64)
             return z, z.copy()
-    with enable_x64():
+    with jax.enable_x64(True):
         total_j, lo_j, hi_j, valid_j = (
             jnp.asarray(total1), jnp.asarray(lo1),
             jnp.asarray(hi1), jnp.asarray(valid1),
@@ -426,7 +425,7 @@ def solve_rows_availability(scheme: str, drift, prob: AllocationProblem,
 
     Returns ``((c2s, c1s, c0s), (taus, ds), masks)`` with shapes
     ``(C, K)`` (masks bool) — the per-cycle numerics mirror
-    ``QueueDrift.rollout_iter`` (f64 rows under ``enable_x64``).
+    ``QueueDrift.rollout_iter`` (f64 rows under ``jax.enable_x64``).
 
     When the drift also exposes ``budget_at`` (a :class:`BatteryDrift`)
     and the scheme is energy-aware, each cycle's solve is additionally
@@ -443,7 +442,7 @@ def solve_rows_availability(scheme: str, drift, prob: AllocationProblem,
     state = drift.state_init(k)
     for c in range(cycles):
         mask = np.asarray(drift.online_at(c, k, state))
-        with enable_x64():
+        with jax.enable_x64(True):
             clock, rate = drift.factors_at(c, k, state)
             clock = np.asarray(clock, np.float64)
             rate = np.asarray(rate, np.float64)
@@ -513,7 +512,7 @@ def _fused_realloc_cycles(params, state0, xs, ys, c2b, c1b, c0b, T1, total1,
     scan never trains through a neutralized allocation. The per-cycle
     ``feas`` flags are returned for the host to raise on.
 
-    Must run under ``enable_x64`` so the allocation math stays f64 while
+    Must run under ``jax.enable_x64`` so the allocation math stays f64 while
     training stays f32 (exogenous drift draws are f32-pinned either way,
     so the traced rows track ``CapacityDrift.coefficient_path`` to 1 f32
     ULP — and ``QueueDrift.rollout`` bitwise — and yield the same integer
@@ -937,7 +936,7 @@ class Orchestrator:
         state0 = (self.drift.state_init(len(tm.c2))
                   if is_state_coupled(self.drift)
                   else jnp.zeros((0,), jnp.float32))
-        with enable_x64():
+        with jax.enable_x64(True):
             (params, _, _), (accs, taus, ds, feas) = _fused_realloc_cycles(
                 self.params, state0, jnp.asarray(xs), jnp.asarray(ys),
                 jnp.asarray(c2b), jnp.asarray(c1b), jnp.asarray(c0b),

@@ -773,10 +773,10 @@ def fleet_scale_sweep(
     Every fleet trains every round (unsampled fleets keep working on their
     stale pull), so one global round of virtual-time T simulates F x k
     busy learners: the reported ``learners_per_vtu`` is exactly F x k.
-    ``mesh=None`` takes ``launch.mesh.host_mesh()`` — a real (2, 4)
-    ``shard_map`` partition under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``, the 1-device
-    mesh elsewhere. Feeds ``benchmarks/fleet_scale.py``."""
+    ``mesh=None`` takes ``launch.mesh.host_mesh()`` — a mesh over every
+    device the process has ((2, 4) under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``, (2, 2) on a
+    four-chip TPU host, one device elsewhere). Feeds ``benchmarks/fleet_scale.py``."""
     from repro.fed.fleet import FleetConfig, FleetEngine, build_fleet_problems
 
     if train is None or test is None:

@@ -1,15 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-
-__all__ = ["tpu_compiler_params"]
-
-
-def tpu_compiler_params(**kwargs):
-    """CompilerParams across jax versions: renamed TPUCompilerParams ->
-    CompilerParams upstream; resolve whichever this jax ships. Imported
-    lazily so the pure-jnp reference paths never touch Pallas."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+"""Pallas TPU kernels (``<name>.py``), their pure-jnp oracles (``ref.py``)
+and the ``use_pallas`` dispatch between the two (``ops.py``)."""

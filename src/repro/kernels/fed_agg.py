@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 __all__ = ["fed_agg_pallas"]
 
 
@@ -48,7 +46,7 @@ def fed_agg_pallas(stacked, weights, *, block_n: int = 2048, interpret: bool = F
         ],
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, flat.shape[1]), stacked.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

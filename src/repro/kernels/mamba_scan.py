@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 __all__ = ["mamba_scan_pallas"]
 
 
@@ -95,7 +93,7 @@ def mamba_scan_pallas(dt, x, b, c, a, h0=None, *, block_d: int = 512,
             jax.ShapeDtypeStruct((bsz, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -4,8 +4,8 @@ Every op has two backends:
   * pure-jnp reference (``repro.kernels.ref`` / ``repro.models.layers``) —
     the default on CPU and the oracle the Pallas kernels are tested against;
   * a Pallas TPU kernel (``use_pallas=True``) with explicit BlockSpec VMEM
-    tiling — the deployment path on real hardware. On CPU the kernels run
-    in ``interpret=True`` mode (tests) only.
+    tiling — the deployment path on a TPU. On CPU the kernels run in
+    ``interpret=True`` mode (tests) only.
 """
 
 from __future__ import annotations
@@ -116,10 +116,10 @@ def train_agg_step(disp, x, y, m, tau, weights, lr, *, loss_fn, max_tau=None,
     ``acc1 = acc + sum_k w_k local_k``.
 
     The unfused path needs a static ``max_tau`` bound (it runs the
-    ``lax.scan`` of ``local_train_stacked``); the Pallas megakernel
-    bounds its in-kernel ``fori_loop`` by the traced ``max(tau)`` and
-    ignores ``max_tau`` — interpret mode is bitwise equal to the unfused
-    path on f32 operands (``tests/test_kernel_parity.py``).
+    ``lax.scan`` of ``local_train_stacked``); the Pallas megakernel runs
+    each learner's in-kernel ``fori_loop`` to its traced ``tau_k`` and
+    ignores ``max_tau``. The two agree to the f32 tolerance stated in
+    ``kernels.train_step`` (``tests/test_kernel_parity.py``).
     """
     if use_pallas:
         from repro.kernels.train_step import train_agg_step_pallas

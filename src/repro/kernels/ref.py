@@ -82,8 +82,8 @@ def train_agg_step_ref(disp, x, y, m, tau, weights, lr, *, loss_fn, max_tau,
                        server=None, acc=None, keep=None, flush=None):
     """Unfused train+aggregate composition — literally
     ``local_train_stacked`` followed by the ``fed_agg_ref`` contractions,
-    so the megakernel's bitwise contract is pinned against the exact ops
-    the scan bodies run today. ``acc=None`` selects the cycle form (plain
+    so the megakernel's f32-tolerance contract is pinned against the exact
+    ops the scan bodies run today. ``acc=None`` selects the cycle form (plain
     weighted aggregation of the trained locals); otherwise the async
     accumulate/flush form ``server' = keep*server + flush*(acc + sum_k
     w_k local_k)``, ``acc' = (1-flush)*(acc + sum_k w_k local_k)``.

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 __all__ = ["swiglu_pallas"]
 
 
@@ -73,7 +71,7 @@ def swiglu_pallas(x, w_gate, w_up, w_down, *, block_m: int = 256,
         out_specs=pl.BlockSpec((block_m, d), lambda mi, fi: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -26,8 +26,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["waterfill_residual_pallas", "waterfill_energy_residual_pallas"]
 
@@ -86,7 +85,7 @@ def waterfill_residual_pallas(
                   mat_spec, mat_spec, col_spec],
         out_specs=col_spec,
         out_shape=jax.ShapeDtypeStruct((bp, 1), dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -172,7 +171,7 @@ def waterfill_energy_residual_pallas(
                   mat_spec, mat_spec, col_spec],
         out_specs=col_spec,
         out_shape=jax.ShapeDtypeStruct((bp, 1), dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 __all__ = ["wkv6_pallas"]
 
 
@@ -86,7 +84,7 @@ def wkv6_pallas(r, k, v, w, u, s0=None, *, block_t: int = 64, interpret: bool = 
             jax.ShapeDtypeStruct((b, h, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

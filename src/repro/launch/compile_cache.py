@@ -1,0 +1,37 @@
+"""JAX's persistent compilation cache for the entry-point scripts.
+
+A script calls ``enable_compile_cache()`` once, before its first compile.
+Importing ``repro`` never turns the cache on: the tests treat warnings as
+errors, and a cache entry written for one backend warns when read back on
+another.
+"""
+
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+import jax
+
+__all__ = ["CACHE_ENV", "enable_compile_cache"]
+
+#: the variable JAX reads its cache directory from
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: the checkout root (``src/repro/launch`` -> three levels up)
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compilation cache on and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``: the directory is part of each entry's key,
+    so it must not move between runs."""
+    path = os.environ.get(CACHE_ENV)
+    if path:
+        return path
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

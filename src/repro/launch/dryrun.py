@@ -28,8 +28,6 @@ import time
 
 import jax
 
-from repro.compat import cost_analysis_dict, set_mesh
-
 from repro.configs import ARCH_NAMES, INPUT_SHAPES, get_config
 from repro.launch.mesh import make_mesh_by_name
 from repro.launch.steps import build_decode, build_prefill, build_train
@@ -79,20 +77,20 @@ def run_one(arch: str, shape_name: str, mesh_name: str, rules_name: str | None =
         specs = model.input_specs(shape)
         bshard = batch_sh(specs)
         jitted = jax.jit(step, in_shardings=(pshard, oshard, bshard), out_shardings=out_sh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(aparams, aopt, specs)
     elif shape.kind == "prefill":
         step, (pshard, batch_sh), aparams = build_prefill(model, mesh, shape, rules)
         specs = model.input_specs(shape)
         bshard = batch_sh(specs)
         jitted = jax.jit(step, in_shardings=(pshard, bshard))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(aparams, specs)
     else:
         step, (pshard, cshard, tshard, lshard), (aparams, acache) = build_decode(model, mesh, shape, rules)
         specs = model.input_specs(shape)
         jitted = jax.jit(step, in_shardings=(pshard, cshard, tshard, lshard))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(aparams, specs["cache"], specs["token"], specs["cache_len"])
     t_lower = time.time() - t0
 
@@ -103,7 +101,7 @@ def run_one(arch: str, shape_name: str, mesh_name: str, rules_name: str | None =
     # ---- analysis -------------------------------------------------------
     # HloCostAnalysis counts while bodies once; keep it for reference but use
     # the loop-aware analyzer (repro.roofline.hlo_cost) for the roofline.
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
 

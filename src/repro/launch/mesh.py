@@ -7,18 +7,15 @@ else in the codebase ever asks for more devices than exist.
 Host-CPU fake-device path: XLA can split the host CPU into N fake devices
 with ``--xla_force_host_platform_device_count=N`` (must be in XLA_FLAGS
 *before* jax initializes — i.e. set in the environment of a fresh process,
-as the fleet-scale CI step and the sharding subprocess tests do). With 8
-fake devices the ``"test"`` spec is a real (2, 4) data×model mesh and
-``shard_map`` partitioning is exercised for real; ``host_mesh()`` picks the
-largest spec the current process can serve so the same code runs 1-device
-eager CI and 8-device sharded CI unchanged.
+as the fleet-scale CI step and the sharding subprocess tests do).
+``host_mesh()`` lays a data×model mesh over every device the process
+sees, so the same code runs 1-device CI, 8-fake-device sharded CI (the
+(2, 4) ``"test"`` shape) and a four-chip TPU host (2, 2) unchanged.
 """
 
 from __future__ import annotations
 
 import jax
-
-from repro import compat
 
 __all__ = [
     "make_production_mesh",
@@ -37,7 +34,7 @@ XLA_HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count"
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 # name -> (shape, axes); "test" variants run inside CI with 8/16 fake devices
@@ -60,7 +57,7 @@ def device_count_for(name: str) -> int:
 
 def make_mesh_by_name(name: str):
     shape, axes = MESH_SPECS[name]
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def host_device_flags(n: int = 8) -> str:
@@ -69,13 +66,11 @@ def host_device_flags(n: int = 8) -> str:
     return f"{XLA_HOST_DEVICES_FLAG}={n}"
 
 
-def host_mesh(prefer: str = "test"):
-    """The largest named mesh this process can actually build: ``prefer``
-    (default ``"test"``, 8 devices) when enough devices exist — real ones
-    or fake host-CPU devices forced via ``host_device_flags`` — else the
-    1-device ``"cpu"`` spec. THE mesh entry point for the fleet engine and
-    its CI step: the same call is a genuine (2, 4) ``shard_map`` partition
-    under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and a
-    trivial 1-device mesh everywhere else."""
-    name = prefer if len(jax.devices()) >= device_count_for(prefer) else "cpu"
-    return make_mesh_by_name(name)
+def host_mesh():
+    """A ``("data", "model")`` mesh over every device this process has:
+    (2, n/2) for an even n >= 4 — the (2, 4) ``"test"`` shape on 8 fake
+    host-CPU devices, (2, 2) on a four-chip TPU host — else (1, n). THE
+    mesh entry point for the fleet engine and its CI step."""
+    n = len(jax.devices())
+    data = 2 if n >= 4 and n % 2 == 0 else 1
+    return jax.make_mesh((data, n // data), ("data", "model"))

@@ -12,8 +12,6 @@ import time
 import numpy as np
 
 import jax
-
-from repro.compat import set_mesh
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_reduced
@@ -55,7 +53,7 @@ def main() -> None:
     prefill = jax.jit(lambda p, bt: model.prefill(p, bt, max_len=max_len))
     decode = jax.jit(model.decode)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         logits, cache, _aux = prefill(params, batch)
         tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)

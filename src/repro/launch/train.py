@@ -17,8 +17,6 @@ import time
 import numpy as np
 
 import jax
-
-from repro.compat import set_mesh
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_reduced
@@ -72,7 +70,7 @@ def main() -> None:
         return b
 
     jitted = jax.jit(step)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(args.steps):
             t0 = time.time()
             batch = {k: jnp.asarray(v) for k, v in with_extras(next(gen)).items()}

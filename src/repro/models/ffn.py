@@ -87,7 +87,7 @@ def _group_dispatch(xg, gates_g, idx_g, p, cfg: ArchConfig, capacity: int):
     cd = cfg.cdtype()
 
     flat_e = idx_g.reshape(-1)                       # (T*k,)
-    flat_t = jnp.repeat(jnp.arange(t), k)            # (T*k,)
+    flat_t = jnp.arange(t * k) // k                  # (T*k,) token of each slot
     flat_g = gates_g.reshape(-1)
 
     order = jnp.argsort(flat_e)
@@ -146,9 +146,7 @@ def moe_apply(cfg: ArchConfig, p, x, *, train: bool = False):
     capacity = _capacity(s, cfg.top_k, cfg.num_experts, cfg.capacity_factor)
     gates = gates.astype(cd)
 
-    from repro.compat import current_mesh, shard_map as _shard_map_compat
-
-    am = current_mesh()
+    am = jax.sharding.get_abstract_mesh()
     batch_axes = tuple(a for a in ("pod", "data") if a in am.axis_names)
     n_shards = 1
     for a in batch_axes:
@@ -159,7 +157,7 @@ def moe_apply(cfg: ArchConfig, p, x, *, train: bool = False):
     # (prefill/decode) are proven and keep the fix. See EXPERIMENTS §Perf.
     if cfg.moe_shard_map and not train and batch_axes and b % n_shards == 0:
         spec = P(batch_axes, None, None)
-        routed = _shard_map_compat(
+        routed = jax.shard_map(
             lambda xg, gg, ig, pp: _routed_vmap(xg, gg, ig, pp, cfg, capacity),
             mesh=am,
             in_specs=(spec, spec, spec, P()),

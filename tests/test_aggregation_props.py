@@ -117,8 +117,6 @@ def test_capacity_drift_path_matches_traced_factors_at():
     cycles = 6
     c2s, c1s, c0s = drift.coefficient_path(tm, cycles)
 
-    from jax.experimental import enable_x64
-
     @jax.jit
     def traced_row(c):
         clock, rate = drift.factors_at(c, k)
@@ -135,7 +133,7 @@ def test_capacity_drift_path_matches_traced_factors_at():
     policy = _jitted_policy("kkt_sai")
     T1, total1, lo1, hi1, valid1 = policy_problem_args(prob)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         for c in range(cycles):
             r2, r1, r0 = traced_row(c)
             # clock factors divide exactly; rate-driven rows to 1 f32 ULP

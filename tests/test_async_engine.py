@@ -556,7 +556,9 @@ def test_run_events_seg_batch_matches_dense(data):
 
 def test_run_events_seg_batch_pallas_matches_seg_batch_unfused(data):
     """seg_batch and the megakernel compose: the compact scan body through
-    ops.train_agg_step is bitwise equal to its unfused twin."""
+    ops.train_agg_step matches its unfused twin — history exactly, params
+    to the megakernel's whole-run f32 tolerance."""
+    from repro.kernels.train_step import F32_RTOL, RUN_ATOL
     train, _ = data
     prob = build_problem(4, 15.0, total_samples=1200, seed=3)
     cfg = AsyncConfig(mode="buffered", buffer_size=4)
@@ -571,4 +573,4 @@ def test_run_events_seg_batch_pallas_matches_seg_batch_unfused(data):
 
     (h0, p0), (h1, p1) = runs
     _assert_history_match(h0, h1)
-    _assert_trees_equal(p0, p1)
+    _assert_trees_equal(p0, p1, rtol=F32_RTOL, atol=RUN_ATOL)

@@ -14,7 +14,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (
     AllocationProblem,
@@ -104,7 +103,7 @@ def test_infinite_budget_reproduces_kkt_sai_everywhere():
         np.testing.assert_array_equal(ba.tau, ref_b.tau)
         np.testing.assert_array_equal(ba.d, ref_b.d)
 
-        with enable_x64():
+        with jax.enable_x64(True):
             args = tuple(jnp.asarray(a) for a in (
                 bp.c2, bp.c1, bp.c0, bp.T, bp.total,
                 bp.d_lo, bp.d_hi, bp.valid,

@@ -23,7 +23,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import BatchedProblems, apply_active_mask, apply_sampling_mask
 from repro.core.solver_batched import batched_policy
@@ -150,7 +149,7 @@ def test_policy_solves_sampled_out_rows_to_zero(seed):
     b, _ = bp.c2.shape
     sampled = np.zeros(b, bool)
     sampled[::2] = True
-    with enable_x64():
+    with jax.enable_x64(True):
         tot, lo, hi, v = apply_sampling_mask(
             jnp.asarray(bp.total, jnp.int64),
             jnp.asarray(bp.d_lo, jnp.float64),
@@ -183,6 +182,25 @@ def test_fleet_padding_is_padded_slot_semantics():
     assert not padded.valid[3:].any()
     assert (padded.d_lo[3:] == 0).all() and (padded.d_hi[3:] == 0).all()
     assert (padded.total[3:] == 0).all()
+
+
+def test_padded_fleet_keeps_its_model(data):
+    """An all-invalid fleet row — what the engine pads F with on a
+    multi-device mesh — has d = 0 everywhere, so its aggregation weights
+    are 0/0. It must keep its model rather than turn it into NaN, which the
+    global merge would carry even at zero weight (0 * NaN) to every fleet."""
+    train, _ = data
+    bp = FleetEngine._pad_problems(
+        build_fleet_problems(3, 3, T=6.0, total_samples=30, seed=2), 4)
+    params = mlp.init(jax.random.key(0))
+    # lr small enough that the real fleets' 10-sample shards do not diverge
+    eng = FleetEngine(FleetConfig(lr=0.01), bp, mlp.loss, params, seed=1,
+                      mesh=_cpu_mesh())
+    assert (eng.d[3] == 0).all()
+    eng.run(train, 2)
+    for leaf in jax.tree_util.tree_leaves((eng.global_params,
+                                           eng.fleet_params)):
+        assert np.isfinite(np.asarray(leaf)).all()
 
 
 def test_partial_participation_staleness(data):

@@ -2,8 +2,8 @@
 
 Needs >= 8 devices — the fleet-scale CI step provides them by setting
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before jax
-initializes, so ``host_mesh()`` resolves to the (2, 4) ``"test"`` spec and
-``compat.shard_map`` genuinely partitions the fleet axis. On fewer
+initializes, so ``host_mesh()`` builds the (2, 4) data x model mesh and
+``jax.shard_map`` genuinely partitions the fleet axis. On fewer
 devices every test here skips (tier-1 covers the 1-device semantics in
 ``tests/test_fleet.py``).
 
@@ -21,7 +21,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.fed.fleet import FleetConfig, FleetEngine, build_fleet_problems
 from repro.launch.mesh import host_device_flags, host_mesh

@@ -6,8 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import cost_analysis_dict as _builtin_cost
-from repro.compat import shard_map as _shard_map
 from repro.roofline.hlo_cost import analyze_hlo
 
 
@@ -46,7 +44,7 @@ def test_matches_builtin_on_loop_free():
     b = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     compiled = jax.jit(f).lower(a, b).compile()
     ours = analyze_hlo(compiled.as_text())
-    builtin = _builtin_cost(compiled)
+    builtin = compiled.cost_analysis()
     assert ours.flops == pytest.approx(builtin["flops"], rel=0.10)
 
 
@@ -55,7 +53,7 @@ def test_builtin_undercounts_scans():
     x = jax.ShapeDtypeStruct((128, 256), jnp.float32)
     ws = jax.ShapeDtypeStruct((16, 256, 256), jnp.float32)
     compiled = jax.jit(_scanned).lower(x, ws).compile()
-    builtin = _builtin_cost(compiled)["flops"]
+    builtin = compiled.cost_analysis()["flops"]
     ours = analyze_hlo(compiled.as_text()).flops
     assert ours > 10 * builtin
 
@@ -92,7 +90,7 @@ def test_collectives_scaled_by_trips():
 
     with mesh:
         g = jax.jit(
-            _shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P("d"), check_vma=False)
+            jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P("d"), check_vma=False)
         )
         compiled = g.lower(jax.ShapeDtypeStruct((8, 16), jnp.float32)).compile()
     ours = analyze_hlo(compiled.as_text())

@@ -19,7 +19,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import QueueDrift
 from repro.core.availability import MarkovAvailability
@@ -180,7 +179,7 @@ def test_s1_policy_is_static_passthrough(seed, s):
     zero deficits the equal and deficit splits coincide."""
     rng = np.random.default_rng(seed)
     k = int(rng.integers(2, 7))
-    with enable_x64():
+    with jax.enable_x64(True):
         c2 = jnp.asarray(rng.uniform(1e-4, 5e-3, (1, k)))
         c1 = jnp.asarray(rng.uniform(1e-5, 1e-3, (1, k)))
         c0 = jnp.asarray(rng.uniform(0.05, 0.3, (1, k)))
@@ -267,7 +266,7 @@ def test_split_never_overcommits_a_learner(seed, s, split, scheme):
 def test_split_weights_laws(seed, s, floor):
     rng = np.random.default_rng(seed)
     deficits = rng.uniform(0.0, 10.0, s)
-    with enable_x64():
+    with jax.enable_x64(True):
         w = np.asarray(cross_model_weights(
             jnp.asarray(deficits), policy="deficit", share_floor=floor))
         # sum exactly representable and <= 1 (2^-20 grid floor)

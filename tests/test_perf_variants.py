@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import set_mesh
 from repro.configs import get_reduced
 from repro.kernels.ref import flash_attention_ref
 from repro.models.layers import flash_attention
@@ -88,6 +87,6 @@ def test_moe_shard_map_matches_plain_vmap():
     }
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     plain = float(m.loss(params, batch))  # no mesh context -> vmap path
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sharded = float(jax.jit(m.loss)(params, batch))  # shard_map path
     assert abs(plain - sharded) < 1e-4

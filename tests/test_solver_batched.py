@@ -356,7 +356,8 @@ def test_fused_realloc_infeasible_drift_raises_from_in_scan_guard():
 
     train, _ = synthetic_mnist(3000, n_test=10, seed=0)
     prob = make_problem(k=4, T=15.0, d=1200)
-    drift = CapacityDrift(fading_sigma_db=30.0, fading_clip_db=30.0, seed=0)
+    # seed 1: cycle 0 is feasible, cycle 1 is not
+    drift = CapacityDrift(fading_sigma_db=30.0, fading_clip_db=30.0, seed=1)
     orch = Orchestrator(MELConfig(T=15.0, total_samples=1200), prob, mlp.loss,
                         mlp.init(jax.random.key(0)), drift=drift)
     with pytest.raises(ValueError, match="cannot absorb") as ei:

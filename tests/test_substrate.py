@@ -241,3 +241,57 @@ def test_local_train_lowers_sharded_over_learners():
         ).lower(params, x, y, m, tau, jnp.float32(0.1))
         compiled = lowered.compile()
     assert compiled is not None
+
+
+def test_compile_cache_dir_from_env_is_left_to_jax(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper returns it and sets
+    nothing itself (JAX reads the variable)."""
+    from repro.launch.compile_cache import CACHE_ENV, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    """Without the variable the cache goes to ``<checkout>/.jax_cache``: a
+    fixed path (part of each entry's key), never a temp or per-run one."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import CACHE_ENV, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv(CACHE_ENV, raising=False)
+    try:
+        path = enable_compile_cache()
+        assert path == enable_compile_cache()
+        assert Path(path) == Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """``chip_smoke.py`` has no CPU fallback: on a CPU-only JAX it exits
+    non-zero, says why, and prints no result line."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, str(root / "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+def test_host_mesh_spans_every_device():
+    from repro.launch.mesh import host_mesh
+
+    mesh = host_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.devices.size == len(jax.devices())
