@@ -116,6 +116,7 @@ from repro.fed.orchestrator import (
     solve_rows_availability,
     solve_rows_state_coupled,
 )
+from repro.fed.tracing import span, to_device
 
 __all__ = [
     "AsyncConfig",
@@ -483,7 +484,8 @@ class AsyncFedEngine:
         # the paper-scheme allocation on the base capacities (used by the
         # barrier path so it matches Orchestrator.run bitwise); event-mode
         # dispatches go through the traced batched_policy instead.
-        self.allocation = SCHEMES[cfg.scheme](problem)
+        with span("allocate", k=k):
+            self.allocation = SCHEMES[cfg.scheme](problem)
         self._alloc_cache: dict[int, tuple[np.ndarray, np.ndarray]] = {}
         self._static_alloc: tuple[np.ndarray, np.ndarray] | None = None
         self._block_masks: np.ndarray | None = None
@@ -1014,13 +1016,15 @@ class AsyncFedEngine:
             )
         self.fault_counters = _zero_fault_counters()   # this run's tallies only
         part = FederatedPartitioner(train, seed=int(self.rng.integers(2**31)))
-        sched = self._build_schedule(part, horizon, max_events)
+        with span("schedule") as sp:
+            sched = self._build_schedule(part, horizon, max_events)
+            segments = _event_segments(sched.arrivals)
+            sp.set_metadata(arrivals=len(sched.arrivals))
         self.fault_counters = sched.counters
         self.energy_ledger = {
             "per_learner": sched.energy_spent,
             "violations": sched.energy_violations,
         }
-        segments = _event_segments(sched.arrivals)
         if not segments:
             return []
         return self._run_groups(
@@ -1255,30 +1259,36 @@ def _staged_group_arrays(groups, train: Dataset, *, mode: str, k_fleet: int,
     (dataset identity, schedule digest): repeated replays of one schedule
     — parameter sweeps, golden-trace replays, the multi-model engine's
     per-model reruns — skip re-staging the full (S, K, d_cap, F) tensor
-    and pay it once per distinct schedule."""
-    key = (id(train), _schedule_digest(
-        groups, mode=mode, k_fleet=k_fleet, d_cap=d_cap, feat=feat,
-        seg_batch=seg_batch,
-    ))
-    hit = _STAGING_CACHE.get(key)
-    # the entry pins the dataset object, so its id cannot be recycled
-    # while the entry lives — an identity check makes that explicit
-    if hit is not None and hit[0] is train:
-        _STAGING_STATS["hits"] += 1
-        return hit[1]
-    _STAGING_STATS["stages"] += 1
-    if seg_batch is None:
-        staged = _stage_groups_dense(
-            groups, train, mode=mode, k_fleet=k_fleet, d_cap=d_cap, feat=feat
-        )
-    else:
-        staged = _stage_groups_compact(
-            groups, train, mode=mode, slots=seg_batch, d_cap=d_cap, feat=feat
-        )
-    while len(_STAGING_CACHE) >= _STAGING_CACHE_MAX:
-        _STAGING_CACHE.pop(next(iter(_STAGING_CACHE)))
-    _STAGING_CACHE[key] = (train, staged)
-    return staged
+    and pay it once per distinct schedule. Runs under a ``fed.stage``
+    span whose stats are the bytes staged (0 on a hit) and ``hit``."""
+    with span("stage") as sp:
+        key = (id(train), _schedule_digest(
+            groups, mode=mode, k_fleet=k_fleet, d_cap=d_cap, feat=feat,
+            seg_batch=seg_batch,
+        ))
+        hit = _STAGING_CACHE.get(key)
+        # the entry pins the dataset object, so its id cannot be recycled
+        # while the entry lives — an identity check makes that explicit
+        if hit is not None and hit[0] is train:
+            _STAGING_STATS["hits"] += 1
+            sp.set_metadata(bytes=0, hit=1)
+            return hit[1]
+        _STAGING_STATS["stages"] += 1
+        if seg_batch is None:
+            staged = _stage_groups_dense(
+                groups, train, mode=mode, k_fleet=k_fleet, d_cap=d_cap,
+                feat=feat,
+            )
+        else:
+            staged = _stage_groups_compact(
+                groups, train, mode=mode, slots=seg_batch, d_cap=d_cap,
+                feat=feat,
+            )
+        while len(_STAGING_CACHE) >= _STAGING_CACHE_MAX:
+            _STAGING_CACHE.pop(next(iter(_STAGING_CACHE)))
+        _STAGING_CACHE[key] = (train, staged)
+        sp.set_metadata(bytes=sum(a.nbytes for a in staged), hit=0)
+        return staged
 
 
 def _run_group_program(params, groups, sched: _Schedule, train: Dataset, *,
@@ -1324,49 +1334,37 @@ def _run_group_program(params, groups, sched: _Schedule, train: Dataset, *,
         seg_batch=seg_batch,
     )
 
-    ex = jnp.asarray(eval_batch[0]) if eval_fn is not None else None
-    ey = jnp.asarray(eval_batch[1]) if eval_fn is not None else None
     disp0 = jax.tree_util.tree_map(
         lambda p: jnp.broadcast_to(p, (k_fleet,) + p.shape),
         params,
     )
     accum0 = jax.tree_util.tree_map(jnp.zeros_like, params)
-    if seg_batch is None:
-        xs, ys, ms, tau_g, wc, keepv, fflag, rmask, pmask = staged
-        params, accs = _bucketed_events(
-            params, disp0, accum0, jnp.asarray(xs), jnp.asarray(ys),
-            jnp.asarray(ms), jnp.asarray(tau_g), jnp.asarray(wc),
-            jnp.asarray(keepv), jnp.asarray(fflag),
-            jnp.asarray(rmask), jnp.asarray(pmask),
-            jnp.asarray(lr, jnp.float32), ex, ey,
+    ex, ey = eval_batch if eval_fn is not None else (None, None)
+    # the staged blocks in the program's argument order, then lr and eval
+    staged_d = to_device(*staged, np.asarray(lr, np.float32), ex, ey)
+    program = _bucketed_events if seg_batch is None else _bucketed_events_compact
+    with span("dispatch"):
+        params, accs = program(
+            params, disp0, accum0, *staged_d,
             max_tau=max_tau, loss_fn=loss_fn, eval_fn=eval_fn,
             use_pallas=use_pallas, interpret=interpret,
         )
-    else:
-        xs, ys, ms, tau_g, wc, keepv, fflag, ids, rms, pms = staged
-        params, accs = _bucketed_events_compact(
-            params, disp0, accum0, jnp.asarray(xs), jnp.asarray(ys),
-            jnp.asarray(ms), jnp.asarray(tau_g), jnp.asarray(wc),
-            jnp.asarray(keepv), jnp.asarray(fflag),
-            jnp.asarray(ids), jnp.asarray(rms), jnp.asarray(pms),
-            jnp.asarray(lr, jnp.float32), ex, ey,
-            max_tau=max_tau, loss_fn=loss_fn, eval_fn=eval_fn,
-            use_pallas=use_pallas, interpret=interpret,
-        )
-    accs = np.asarray(accs)
+    with span("wait"):
+        accs = np.asarray(accs)
 
-    history: list[dict] = []
-    group: list[_Arrival] = []
-    for i, evs in enumerate(groups):
-        flushes = [a for a in evs if a.flush]
-        for a in evs:
-            group.append(a)
-            if a.flush:
-                rec = _flush_row(a, group, mode)
-                if eval_fn is not None and a is flushes[-1]:
-                    rec["accuracy"] = float(accs[i])
-                history.append(rec)
-                group = []
+    with span("history"):
+        history: list[dict] = []
+        group: list[_Arrival] = []
+        for i, evs in enumerate(groups):
+            flushes = [a for a in evs if a.flush]
+            for a in evs:
+                group.append(a)
+                if a.flush:
+                    rec = _flush_row(a, group, mode)
+                    if eval_fn is not None and a is flushes[-1]:
+                        rec["accuracy"] = float(accs[i])
+                    history.append(rec)
+                    group = []
     return params, history
 
 
@@ -1426,15 +1424,16 @@ def _bucketed_events(server, disp, accum, xs, ys, ms, taus, wcs, keeps, fs,
             )
             # only flush buckets' accuracies are ever read back (buffered
             # accumulation buckets would be dead eval compute)
-            a_out = (
-                jax.lax.cond(
-                    f > 0,
-                    lambda s: eval_fn(s, eval_x, eval_y).astype(jnp.float32),
-                    lambda s: jnp.float32(0),
-                    server1,
+            with jax.named_scope("eval"):
+                a_out = (
+                    jax.lax.cond(
+                        f > 0,
+                        lambda s: eval_fn(s, eval_x, eval_y).astype(jnp.float32),
+                        lambda s: jnp.float32(0),
+                        server1,
+                    )
+                    if eval_fn is not None else jnp.float32(0)
                 )
-                if eval_fn is not None else jnp.float32(0)
-            )
             return (server1, dp1, acc2), a_out
 
         def skip(op):
@@ -1505,15 +1504,16 @@ def _bucketed_events_compact(server, disp, accum, xs, ys, ms, taus, wcs,
                 ),
                 dp, server1, server,
             )
-            a_out = (
-                jax.lax.cond(
-                    f > 0,
-                    lambda s: eval_fn(s, eval_x, eval_y).astype(jnp.float32),
-                    lambda s: jnp.float32(0),
-                    server1,
+            with jax.named_scope("eval"):
+                a_out = (
+                    jax.lax.cond(
+                        f > 0,
+                        lambda s: eval_fn(s, eval_x, eval_y).astype(jnp.float32),
+                        lambda s: jnp.float32(0),
+                        server1,
+                    )
+                    if eval_fn is not None else jnp.float32(0)
                 )
-                if eval_fn is not None else jnp.float32(0)
-            )
             return (server1, dp1, acc2), a_out
 
         def skip(op):

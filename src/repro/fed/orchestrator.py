@@ -79,6 +79,7 @@ from repro.core.solver_batched import apply_active_mask
 from repro.core.staleness import avg_staleness, max_staleness
 from repro.core.time_model import is_state_coupled
 from repro.data.pipeline import Dataset, FederatedPartitioner
+from repro.fed.tracing import span, to_device
 
 __all__ = ["MELConfig", "Orchestrator", "local_train", "local_train_stacked"]
 
@@ -133,7 +134,8 @@ def local_train_stacked(stacked, x, y, mask, tau, lr, *, max_tau: int, loss_fn):
         p, _ = jax.lax.scan(step, params, jnp.arange(max_tau))
         return p
 
-    return jax.vmap(one_learner)(stacked, x, y, mask, tau)
+    with jax.named_scope("local_train"):
+        return jax.vmap(one_learner)(stacked, x, y, mask, tau)
 
 
 def local_train(global_params, x, y, mask, tau, lr, *, max_tau: int, loss_fn):
@@ -146,6 +148,15 @@ def local_train(global_params, x, y, mask, tau, lr, *, max_tau: int, loss_fn):
     return local_train_stacked(
         stacked, x, y, mask, tau, lr, max_tau=max_tau, loss_fn=loss_fn
     )
+
+
+def _drawn(part: FederatedPartitioner, d) -> "list[Dataset]":
+    """``part.draw(d)`` under a ``fed.draw`` span. The shards stay a
+    temporary of the staging call that takes them, freed before the next
+    cycle allocates: held across that allocation they cost the host about
+    a tenth more staging time."""
+    with span("draw"):
+        return part.draw(d)
 
 
 def _stage_shards(shards: "list[Dataset]", d_max: int, feat: int):
@@ -185,7 +196,9 @@ def _fused_cycles(params, xs, ys, ms, tau, weights, lr, eval_x, eval_y, *,
             disp, x, y, m, tau, weights, lr, loss_fn=loss_fn,
             max_tau=max_tau, use_pallas=use_pallas, interpret=interpret,
         )
-        acc = eval_fn(new, eval_x, eval_y) if eval_fn is not None else jnp.float32(0)
+        with jax.named_scope("eval"):
+            acc = (eval_fn(new, eval_x, eval_y) if eval_fn is not None
+                   else jnp.float32(0))
         return new, acc
 
     return jax.lax.scan(one_cycle, params, (xs, ys, ms))
@@ -215,9 +228,10 @@ def _local_train_dynamic(params, x, y, mask, tau, lr, *, loss_fn):
         p = jax.vmap(functools.partial(one_step, i))(p, x, y, mask, tau)
         return p, i + 1
 
-    p, _ = jax.lax.while_loop(
-        lambda s: s[1] < tau_max, body, (stacked, jnp.zeros((), tau.dtype))
-    )
+    with jax.named_scope("local_train"):
+        p, _ = jax.lax.while_loop(
+            lambda s: s[1] < tau_max, body, (stacked, jnp.zeros((), tau.dtype))
+        )
     return p
 
 
@@ -350,7 +364,7 @@ def solve_policy_row(scheme: str, c2r, c1r, c0r, prob: AllocationProblem,
         if not act.any():
             z = np.zeros(k, np.int64)
             return z, z.copy()
-    with jax.enable_x64(True):
+    with span("allocate", k=k), jax.enable_x64(True):
         total_j, lo_j, hi_j, valid_j = (
             jnp.asarray(total1), jnp.asarray(lo1),
             jnp.asarray(hi1), jnp.asarray(valid1),
@@ -539,13 +553,10 @@ def _fused_realloc_cycles(params, state0, xs, ys, c2b, c1b, c0b, T1, total1,
             c2 = c2b / clock.astype(c2b.dtype)[None]
             c1 = c1b / rate.astype(c1b.dtype)[None]
             c0 = c0b / rate.astype(c0b.dtype)[None]
-        if energy1 is None:
+        energy = () if energy1 is None else (energy1,)
+        with jax.named_scope("policy_solve"):
             tau_b, d_b, feas_b = policy(
-                c2, c1, c0, T1, total1, lo1, hi1, valid1
-            )
-        else:
-            tau_b, d_b, feas_b = policy(
-                c2, c1, c0, T1, total1, lo1, hi1, valid1, energy1
+                c2, c1, c0, T1, total1, lo1, hi1, valid1, *energy
             )
         tau, d, feas = tau_b[0], d_b[0], feas_b[0]
         ok = feas & jnp.logical_not(dead)
@@ -579,8 +590,9 @@ def _fused_realloc_cycles(params, state0, xs, ys, c2b, c1b, c0b, T1, total1,
                 new = jax.tree_util.tree_map(
                     lambda leaf: ops.fed_agg(leaf, w), locals_
                 )
-            acc = (eval_fn(new, eval_x, eval_y).astype(jnp.float32)
-                   if eval_fn is not None else jnp.float32(0))
+            with jax.named_scope("eval"):
+                acc = (eval_fn(new, eval_x, eval_y).astype(jnp.float32)
+                       if eval_fn is not None else jnp.float32(0))
             return new, acc
 
         def skip_cycle(p):
@@ -622,7 +634,8 @@ class Orchestrator:
                 "churn scenarios through fed.async_engine.AsyncFedEngine"
             )
         self.drift = drift
-        self.allocation = SCHEMES[mel.scheme](problem)
+        with span("allocate", k=problem.num_learners):
+            self.allocation = SCHEMES[mel.scheme](problem)
 
     # -- time-varying capacities --------------------------------------------
     def _coefficient_path(self, cycles: int):
@@ -839,11 +852,13 @@ class Orchestrator:
 
         # identical shard sequence to the eager path (same rng consumption)
         part = FederatedPartitioner(train, seed=int(self.rng.integers(2**31)))
-        xs = np.zeros((cycles, k, d_max, feat), np.float32)
-        ys = np.zeros((cycles, k, d_max), np.int32)
-        ms = np.zeros((cycles, k, d_max), np.float32)
+        with span("stage"):
+            xs = np.zeros((cycles, k, d_max, feat), np.float32)
+            ys = np.zeros((cycles, k, d_max), np.int32)
+            ms = np.zeros((cycles, k, d_max), np.float32)
         for c in range(cycles):
-            xs[c], ys[c], ms[c] = _stage_shards(part.draw(d), d_max, feat)
+            with span("stage", bytes=xs[c].nbytes + ys[c].nbytes + ms[c].nbytes):
+                xs[c], ys[c], ms[c] = _stage_shards(_drawn(part, d), d_max, feat)
 
         if self.mel.aggregation == "staleness":
             w = staleness_weights(tau, d, gamma=self.mel.staleness_gamma)
@@ -852,33 +867,35 @@ class Orchestrator:
 
         if eval_fn is not None and eval_batch is None:
             raise ValueError("run_fused needs eval_batch=(x, y) with eval_fn")
-        ex = jnp.asarray(eval_batch[0]) if eval_fn is not None else None
-        ey = jnp.asarray(eval_batch[1]) if eval_fn is not None else None
+        ex, ey = eval_batch if eval_fn is not None else (None, None)
+        xs_d, ys_d, ms_d, tau_d, w_d, lr_d, ex_d, ey_d = to_device(
+            xs, ys, ms, tau, w, np.asarray(self.mel.lr, np.float32), ex, ey)
 
         max_tau = max(int(tau.max()), 1)
-        self.params, accs = _fused_cycles(
-            self.params, jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(ms),
-            jnp.asarray(tau), jnp.asarray(w),
-            jnp.asarray(self.mel.lr, jnp.float32), ex, ey,
-            max_tau=max_tau, loss_fn=self.loss_fn, eval_fn=eval_fn,
-            use_pallas=use_pallas, interpret=interpret,
-        )
-        accs = np.asarray(accs)
+        with span("dispatch"):
+            self.params, accs = _fused_cycles(
+                self.params, xs_d, ys_d, ms_d, tau_d, w_d, lr_d, ex_d, ey_d,
+                max_tau=max_tau, loss_fn=self.loss_fn, eval_fn=eval_fn,
+                use_pallas=use_pallas, interpret=interpret,
+            )
+        with span("wait"):
+            accs = np.asarray(accs)
 
-        history = []
-        for c in range(cycles):
-            rec = {
-                "max_staleness": max_staleness(tau),
-                "avg_staleness": avg_staleness(tau),
-                "tau": tau.copy(),
-                "d": d.copy(),
-                "wall_clock_s": self.mel.T,
-                "cycle": c,
-                "elapsed_s": (c + 1) * self.mel.T,
-            }
-            if eval_fn is not None:
-                rec["accuracy"] = float(accs[c])
-            history.append(rec)
+        with span("history"):
+            history = []
+            for c in range(cycles):
+                rec = {
+                    "max_staleness": max_staleness(tau),
+                    "avg_staleness": avg_staleness(tau),
+                    "tau": tau.copy(),
+                    "d": d.copy(),
+                    "wall_clock_s": self.mel.T,
+                    "cycle": c,
+                    "elapsed_s": (c + 1) * self.mel.T,
+                }
+                if eval_fn is not None:
+                    rec["accuracy"] = float(accs[c])
+                history.append(rec)
         return history
 
     # -- fused fast path with in-scan reallocation ----------------------------
@@ -921,42 +938,50 @@ class Orchestrator:
         # identical rng consumption to the eager path: one flat draw of the
         # (constant) per-cycle total; the split by d happens in the scan
         part = FederatedPartitioner(train, seed=int(self.rng.integers(2**31)))
-        xs = np.zeros((cycles, total, feat), np.float32)
-        ys = np.zeros((cycles, total), np.int32)
+        with span("stage"):
+            xs = np.zeros((cycles, total, feat), np.float32)
+            ys = np.zeros((cycles, total), np.int32)
         for c in range(cycles):
-            idx = part.draw_indices(total)
-            xs[c] = train.x[idx]
-            ys[c] = train.y[idx]
+            with span("stage", bytes=xs[c].nbytes + ys[c].nbytes):
+                with span("draw"):
+                    idx = part.draw_indices(total)
+                xs[c] = train.x[idx]
+                ys[c] = train.y[idx]
 
         if eval_fn is not None and eval_batch is None:
             raise ValueError("run_fused needs eval_batch=(x, y) with eval_fn")
-        ex = jnp.asarray(eval_batch[0]) if eval_fn is not None else None
-        ey = jnp.asarray(eval_batch[1]) if eval_fn is not None else None
+        # the eval batch goes over before x64 is on, so it stays f32
+        ex_d, ey_d = to_device(*(eval_batch if eval_fn is not None
+                                 else (None, None)))
 
         state0 = (self.drift.state_init(len(tm.c2))
                   if is_state_coupled(self.drift)
                   else jnp.zeros((0,), jnp.float32))
         with jax.enable_x64(True):
-            (params, _, _), (accs, taus, ds, feas) = _fused_realloc_cycles(
-                self.params, state0, jnp.asarray(xs), jnp.asarray(ys),
-                jnp.asarray(c2b), jnp.asarray(c1b), jnp.asarray(c0b),
-                jnp.asarray(T1), jnp.asarray(total1), jnp.asarray(lo1),
-                jnp.asarray(hi1), jnp.asarray(valid1),
-                (tuple(jnp.asarray(e) for e in energy1)
-                 if energy1 is not None else None),
-                jnp.asarray(self.mel.staleness_gamma, jnp.float64),
-                jnp.asarray(self.mel.lr, jnp.float32), ex, ey,
-                d_cap=d_cap, loss_fn=self.loss_fn,
-                eval_fn=eval_fn, policy=policy,
-                aggregation=self.mel.aggregation, drift=self.drift,
-                use_pallas=use_pallas, interpret=interpret,
-            )
+            (xs_d, ys_d, c2_d, c1_d, c0_d, T1_d, total1_d, lo1_d, hi1_d,
+             valid1_d, gamma_d, lr_d, *energy_d) = to_device(
+                xs, ys, c2b, c1b, c0b, T1, total1, lo1, hi1, valid1,
+                np.asarray(self.mel.staleness_gamma, np.float64),
+                np.asarray(self.mel.lr, np.float32), *(energy1 or ()))
+            with span("dispatch"):
+                (params, _, _), (accs, taus, ds, feas) = _fused_realloc_cycles(
+                    self.params, state0, xs_d, ys_d, c2_d, c1_d, c0_d, T1_d,
+                    total1_d, lo1_d, hi1_d, valid1_d,
+                    tuple(energy_d) if energy1 is not None else None,
+                    gamma_d, lr_d, ex_d, ey_d,
+                    d_cap=d_cap, loss_fn=self.loss_fn,
+                    eval_fn=eval_fn, policy=policy,
+                    aggregation=self.mel.aggregation, drift=self.drift,
+                    use_pallas=use_pallas, interpret=interpret,
+                )
             # the input params buffer was donated: re-point at the scan
             # carry BEFORE any raise so the orchestrator stays usable (the
             # in-scan dead-latch guarantees it holds the params trained
             # through the feasible prefix only)
             self.params = params
-            accs, taus, ds, feas = (np.asarray(a) for a in (accs, taus, ds, feas))
+            with span("wait"):
+                accs, taus, ds, feas = (np.asarray(a)
+                                        for a in (accs, taus, ds, feas))
         if not feas.all():
             bad = int(np.flatnonzero(~feas)[0])
             raise ValueError(
@@ -964,22 +989,23 @@ class Orchestrator:
                 f"d samples (drifted capacities at cycle {bad})"
             )
 
-        history = []
-        for c in range(cycles):
-            tau_c = taus[c].astype(np.int64)
-            d_c = ds[c].astype(np.int64)
-            rec = {
-                "max_staleness": max_staleness(tau_c),
-                "avg_staleness": avg_staleness(tau_c),
-                "tau": tau_c,
-                "d": d_c,
-                "wall_clock_s": self.mel.T,
-                "cycle": c,
-                "elapsed_s": (c + 1) * self.mel.T,
-            }
-            if eval_fn is not None:
-                rec["accuracy"] = float(accs[c])
-            history.append(rec)
+        with span("history"):
+            history = []
+            for c in range(cycles):
+                tau_c = taus[c].astype(np.int64)
+                d_c = ds[c].astype(np.int64)
+                rec = {
+                    "max_staleness": max_staleness(tau_c),
+                    "avg_staleness": avg_staleness(tau_c),
+                    "tau": tau_c,
+                    "d": d_c,
+                    "wall_clock_s": self.mel.T,
+                    "cycle": c,
+                    "elapsed_s": (c + 1) * self.mel.T,
+                }
+                if eval_fn is not None:
+                    rec["accuracy"] = float(accs[c])
+                history.append(rec)
         self.allocation = Allocation(
             tau=history[-1]["tau"], d=history[-1]["d"],
             method=f"{self.mel.scheme}_drift",

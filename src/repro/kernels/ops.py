@@ -10,6 +10,7 @@ Every op has two backends:
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
@@ -54,13 +55,15 @@ def wkv6(r, k, v, w, u, s0=None, *, use_pallas=False, interpret=False, unroll=1,
 
 
 def fed_agg(stacked, weights, *, use_pallas=False, interpret=False):
-    """Weighted sum over the leading learner axis of a stacked tensor."""
-    if use_pallas:
-        from repro.kernels.fed_agg import fed_agg_pallas
+    """Weighted sum over the leading learner axis of a stacked tensor
+    (device scope ``aggregate``)."""
+    with jax.named_scope("aggregate"):
+        if use_pallas:
+            from repro.kernels.fed_agg import fed_agg_pallas
 
-        return fed_agg_pallas(stacked, weights, interpret=interpret)
-    w = weights.reshape((-1,) + (1,) * (stacked.ndim - 1)).astype(jnp.float32)
-    return (stacked.astype(jnp.float32) * w).sum(axis=0).astype(stacked.dtype)
+            return fed_agg_pallas(stacked, weights, interpret=interpret)
+        w = weights.reshape((-1,) + (1,) * (stacked.ndim - 1)).astype(jnp.float32)
+        return (stacked.astype(jnp.float32) * w).sum(axis=0).astype(stacked.dtype)
 
 
 def waterfill_residual(tau_star, c2, c1, c0, T, d_lo, d_hi, total, *,
@@ -120,15 +123,19 @@ def train_agg_step(disp, x, y, m, tau, weights, lr, *, loss_fn, max_tau=None,
     each learner's in-kernel ``fori_loop`` to its traced ``tau_k`` and
     ignores ``max_tau``. The two agree to the f32 tolerance stated in
     ``kernels.train_step`` (``tests/test_kernel_parity.py``).
+
+    Device scopes: the unfused path's ops fall in ``local_train`` and
+    ``aggregate``; the megakernel is one custom call, in ``local_train``.
     """
     if use_pallas:
         from repro.kernels.train_step import train_agg_step_pallas
 
-        return train_agg_step_pallas(
-            disp, x, y, m, tau, weights, lr, loss_fn=loss_fn,
-            server=server, acc=acc, keep=keep, flush=flush,
-            interpret=interpret,
-        )
+        with jax.named_scope("local_train"):
+            return train_agg_step_pallas(
+                disp, x, y, m, tau, weights, lr, loss_fn=loss_fn,
+                server=server, acc=acc, keep=keep, flush=flush,
+                interpret=interpret,
+            )
     from repro.kernels.ref import train_agg_step_ref
 
     if max_tau is None:

@@ -93,24 +93,25 @@ def train_agg_step_ref(disp, x, y, m, tau, weights, lr, *, loss_fn, max_tau,
 
     locals_ = local_train_stacked(disp, x, y, m, tau, lr,
                                   max_tau=max_tau, loss_fn=loss_fn)
-    w = jnp.asarray(weights, jnp.float32)
-    if acc is None:
-        new = jax.tree_util.tree_map(lambda l: fed_agg_ref(l, w), locals_)
-        return new, None
-    one = jnp.ones((1,), jnp.float32)
-    w_acc = jnp.concatenate([one, w])
-    acc1 = jax.tree_util.tree_map(
-        lambda a, l: fed_agg_ref(jnp.concatenate([a[None], l], axis=0), w_acc),
-        acc, locals_,
-    )
-    keep = jnp.asarray(keep, jnp.float32)
-    flush = jnp.asarray(flush, jnp.float32)
-    w_flush = jnp.stack([keep, flush])
-    server1 = jax.tree_util.tree_map(
-        lambda s, a: fed_agg_ref(jnp.stack([s, a]), w_flush), server, acc1
-    )
-    acc2 = jax.tree_util.tree_map(lambda a: (1.0 - flush) * a, acc1)
-    return server1, acc2
+    with jax.named_scope("aggregate"):
+        w = jnp.asarray(weights, jnp.float32)
+        if acc is None:
+            new = jax.tree_util.tree_map(lambda l: fed_agg_ref(l, w), locals_)
+            return new, None
+        one = jnp.ones((1,), jnp.float32)
+        w_acc = jnp.concatenate([one, w])
+        acc1 = jax.tree_util.tree_map(
+            lambda a, l: fed_agg_ref(jnp.concatenate([a[None], l], axis=0), w_acc),
+            acc, locals_,
+        )
+        keep = jnp.asarray(keep, jnp.float32)
+        flush = jnp.asarray(flush, jnp.float32)
+        w_flush = jnp.stack([keep, flush])
+        server1 = jax.tree_util.tree_map(
+            lambda s, a: fed_agg_ref(jnp.stack([s, a]), w_flush), server, acc1
+        )
+        acc2 = jax.tree_util.tree_map(lambda a: (1.0 - flush) * a, acc1)
+        return server1, acc2
 
 
 def mamba_scan_ref(dt, x, b, c, a, h0=None):

@@ -245,7 +245,7 @@ def test_local_train_lowers_sharded_over_learners():
 
 def test_compile_cache_dir_from_env_is_left_to_jax(monkeypatch, tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set, the helper returns it and sets
-    nothing itself (JAX reads the variable)."""
+    no directory itself (JAX reads the variable)."""
     from repro.launch.compile_cache import CACHE_ENV, enable_compile_cache
 
     before = jax.config.jax_compilation_cache_dir
@@ -270,6 +270,31 @@ def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == path
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_keys_hold_op_metadata(monkeypatch, tmp_path):
+    """Cache keys include op metadata (the engines' named scopes, which
+    profiles read), with source paths relative to the checkout: a program
+    that changes only its scopes is compiled again, and a checkout that
+    moves still hits."""
+    import re
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    keys = ("jax_compilation_cache_include_metadata_in_key",
+            "jax_hlo_source_file_canonicalization_regex")
+    before = {k: getattr(jax.config, k) for k in keys}
+    monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path))
+    try:
+        compile_cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        pattern = jax.config.jax_hlo_source_file_canonicalization_regex
+        source = str(Path(compile_cache.__file__).resolve())
+        assert re.sub(pattern, "", source) == "src/repro/launch/compile_cache.py"
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
 
 
 def test_chip_smoke_refuses_to_run_without_a_tpu():
