@@ -19,13 +19,12 @@ Two execution paths:
     (NumPy shard staging -> jit local_train -> aggregate). Supports
     per-cycle re-allocation and arbitrary host eval callbacks.
   * ``run_fused`` (or ``run(..., fused=True)``) — fast path: shards for
-    ALL cycles are drawn up front, padded into one (C, K, d_max, F)
-    device-resident tensor, and allocate -> local_train ->
-    staleness-weighted aggregation runs as a single jitted ``lax.scan``
-    over global cycles with the carried params buffer donated. The
-    aggregation contraction goes through ``kernels.ops.fed_agg``
-    (Pallas on TPU via ``use_pallas=True``). Trades C× shard memory for
-    zero per-cycle host staging.
+    ALL cycles are drawn up front as one (C, K, d_max) block of sample
+    indices (``SampleIndex``), the training set goes to the device once,
+    and gather -> local_train -> staleness-weighted aggregation runs as a
+    single jitted ``lax.scan`` over global cycles with the carried params
+    buffer donated. The aggregation contraction goes through
+    ``kernels.ops.fed_agg`` (Pallas on TPU via ``use_pallas=True``).
 
 Adaptive in-scan reallocation: with ``reallocate=True`` (both paths) and a
 ``CapacityDrift`` model, the allocation program is re-solved EVERY cycle
@@ -51,7 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import numpy as np
 
@@ -150,19 +149,34 @@ def local_train(global_params, x, y, mask, tau, lr, *, max_tau: int, loss_fn):
     )
 
 
-def _drawn(part: FederatedPartitioner, d) -> "list[Dataset]":
-    """``part.draw(d)`` under a ``fed.draw`` span. The shards stay a
-    temporary of the staging call that takes them, freed before the next
-    cycle allocates: held across that allocation they cost the host about
-    a tenth more staging time."""
-    with span("draw"):
-        return part.draw(d)
+class SampleIndex(NamedTuple):
+    """A campaign's shards as sample indices into the training set, which
+    the fused programs hold on the device whole and gather from one cycle
+    at a time, in place of host-staged shard blocks.
+
+    idx : int32 (C, K, d_max) per-learner slots of a static allocation
+        (padded slots hold 0), or (C, total) flat per-cycle draws that the
+        reallocating scan splits by its traced d
+    x, y : the training set, (N, F) f32 and (N,) int32
+    d : (K,) int32 shard sizes of a static allocation, which mask its
+        padded slots; None for the flat draws
+    """
+
+    idx: jax.Array
+    x: jax.Array
+    y: jax.Array
+    d: jax.Array | None = None
+
+
+def _device_table(train: Dataset):
+    """``train`` as the f32 features and int32 labels the programs read."""
+    return np.asarray(train.x, np.float32), np.asarray(train.y, np.int32)
 
 
 def _stage_shards(shards: "list[Dataset]", d_max: int, feat: int):
     """Zero-pad per-learner shards into (K, d_max, ...) host arrays with a
-    validity mask — shared by the eager per-cycle path and the fused
-    pre-staging so their padding semantics cannot diverge."""
+    validity mask, the eager per-cycle path's operands (the fused programs
+    build the same operands on the device from a ``SampleIndex``)."""
     k = len(shards)
     x = np.zeros((k, d_max, feat), np.float32)
     y = np.zeros((k, d_max), np.int32)
@@ -178,16 +192,29 @@ def _stage_shards(shards: "list[Dataset]", d_max: int, feat: int):
     static_argnames=("max_tau", "loss_fn", "eval_fn", "use_pallas", "interpret"),
     donate_argnums=(0,),
 )
-def _fused_cycles(params, xs, ys, ms, tau, weights, lr, eval_x, eval_y, *,
+def _fused_cycles(params, shards, tau, weights, lr, eval_x, eval_y, *,
                   max_tau: int, loss_fn, eval_fn, use_pallas: bool,
                   interpret: bool):
     """One XLA program for C global cycles: scan(allocated local_train ->
-    fed_agg) with the params carry donated. xs: (C, K, d_max, F);
-    ys/ms: (C, K, d_max); tau/weights: (K,)."""
+    fed_agg) with the params carry donated. tau/weights: (K,).
+
+    ``shards`` is a ``SampleIndex`` with (C, K, d_max) slots: each cycle
+    gathers its rows from the device-resident training set before its
+    local steps, with padded rows and labels zero and the mask from ``d``
+    (the eager path's ``_stage_shards`` operands)."""
     from repro.kernels import ops
 
-    def one_cycle(p, batch):
-        x, y, m = batch
+    valid = jnp.arange(shards.idx.shape[-1]) < shards.d[:, None]
+    mask = valid.astype(jnp.float32)
+
+    def batch_of(idx_c):
+        # every index is in range: clip only spares the bounds masks
+        x = jnp.take(shards.x, idx_c, axis=0, mode="clip")
+        y = jnp.take(shards.y, idx_c, axis=0, mode="clip")
+        return jnp.where(valid[..., None], x, 0), jnp.where(valid, y, 0), mask
+
+    def one_cycle(p, idx_c):
+        x, y, m = batch_of(idx_c)
         k = x.shape[0]
         disp = jax.tree_util.tree_map(
             lambda leaf: jnp.broadcast_to(leaf, (k,) + leaf.shape), p
@@ -201,7 +228,7 @@ def _fused_cycles(params, xs, ys, ms, tau, weights, lr, eval_x, eval_y, *,
                    else jnp.float32(0))
         return new, acc
 
-    return jax.lax.scan(one_cycle, params, (xs, ys, ms))
+    return jax.lax.scan(one_cycle, params, shards.idx)
 
 
 def _local_train_dynamic(params, x, y, mask, tau, lr, *, loss_fn):
@@ -493,7 +520,7 @@ def _weights_traced(tau, d, *, aggregation: str, gamma):
                      "aggregation", "drift", "use_pallas", "interpret"),
     donate_argnums=(0,),
 )
-def _fused_realloc_cycles(params, state0, xs, ys, c2b, c1b, c0b, T1, total1,
+def _fused_realloc_cycles(params, state0, shards, c2b, c1b, c0b, T1, total1,
                           lo1, hi1, valid1, energy1, gamma, lr, eval_x,
                           eval_y, *, d_cap: int, loss_fn, eval_fn, policy,
                           aggregation: str, drift, use_pallas: bool,
@@ -508,7 +535,8 @@ def _fused_realloc_cycles(params, state0, xs, ys, c2b, c1b, c0b, T1, total1,
     params : model pytree (the scan carry; this buffer is donated)
     state0 : (K,) f32 drift state for a state-coupled ``drift``
         (``QueueDrift.state_init``), else a (0,) placeholder
-    xs, ys : (C, total, F) / (C, total) flat per-cycle sample tensors
+    shards : a ``SampleIndex`` of (C, total) flat per-cycle draws into
+        the device-resident training set
     c2b, c1b, c0b : (1, K) f64 BASE capacity rows — per-cycle drifted rows
         are generated INSIDE the scan by ``drift.factors_at`` on the
         traced cycle index (and, for a state-coupled drift, the carried
@@ -536,13 +564,13 @@ def _fused_realloc_cycles(params, state0, xs, ys, c2b, c1b, c0b, T1, total1,
     per-cycle stacked outputs."""
     from repro.kernels import ops
 
-    total = xs.shape[1]
     k = c2b.shape[1]
     state_coupled = is_state_coupled(drift)
+    cycles, total = shards.idx.shape
 
     def one_cycle(carry, inp):
         p, qstate, dead = carry
-        x_flat, y_flat, cyc = inp
+        flat_idx, cyc = inp
         if drift is None:
             c2, c1, c0 = c2b, c1b, c0b
         else:
@@ -570,8 +598,10 @@ def _fused_realloc_cycles(params, state0, xs, ys, c2b, c1b, c0b, T1, total1,
             gidx = off[:, None] + j[None, :]
             m = j[None, :] < d[:, None]
             safe = jnp.clip(gidx, 0, total - 1)
-            x = jnp.take(x_flat, safe, axis=0)          # (K, d_cap, F)
-            y = jnp.take(y_flat, safe, axis=0)          # (K, d_cap)
+            rows = jnp.take(flat_idx, safe, axis=0)          # (K, d_cap)
+            # every row is in range: clip only spares the bounds masks
+            x = jnp.take(shards.x, rows, axis=0, mode="clip")  # (K, d_cap, F)
+            y = jnp.take(shards.y, rows, axis=0, mode="clip")  # (K, d_cap)
 
             if use_pallas:
                 # megakernel path: the in-kernel fori_loop bounds itself
@@ -604,9 +634,8 @@ def _fused_realloc_cycles(params, state0, xs, ys, c2b, c1b, c0b, T1, total1,
             qstate = jnp.where(ok, q_new, qstate)
         return (p_new, qstate, dead | ~feas), (acc, tau, d, feas)
 
-    cycle_idx = jnp.arange(xs.shape[0])
     carry0 = (params, state0, jnp.zeros((), bool))
-    return jax.lax.scan(one_cycle, carry0, (xs, ys, cycle_idx))
+    return jax.lax.scan(one_cycle, carry0, (shards.idx, jnp.arange(cycles)))
 
 
 class Orchestrator:
@@ -794,7 +823,9 @@ class Orchestrator:
         Parameters
         ----------
         train : Dataset to draw per-cycle shards from (identical rng
-            consumption to the eager path for the same engine seed).
+            consumption to the eager path for the same engine seed). It
+            is copied to the device once per call; the host stages each
+            cycle's sample indices and the program gathers the shards.
         cycles : number of global cycles C to scan over.
         eval_fn : optional jit-traceable ``(params, x, y) -> scalar``
             (e.g. ``mlp.accuracy``), evaluated inside the scan each cycle
@@ -848,17 +879,18 @@ class Orchestrator:
         d = np.asarray(alloc.d)
         k = len(d)
         d_max = int(d.max())
-        feat = train.x.shape[1]
 
         # identical shard sequence to the eager path (same rng consumption)
         part = FederatedPartitioner(train, seed=int(self.rng.integers(2**31)))
+        # row-major fill of the valid slots is part.draw's split by d
+        slots = np.arange(d_max) < d[:, None]
         with span("stage"):
-            xs = np.zeros((cycles, k, d_max, feat), np.float32)
-            ys = np.zeros((cycles, k, d_max), np.int32)
-            ms = np.zeros((cycles, k, d_max), np.float32)
+            idx = np.zeros((cycles, k, d_max), np.int32)
         for c in range(cycles):
-            with span("stage", bytes=xs[c].nbytes + ys[c].nbytes + ms[c].nbytes):
-                xs[c], ys[c], ms[c] = _stage_shards(_drawn(part, d), d_max, feat)
+            with span("stage", bytes=idx[c].nbytes):
+                with span("draw"):
+                    idx[c][slots] = part.draw_indices(int(d.sum()))
+        shards = SampleIndex(idx, *_device_table(train), d.astype(np.int32))
 
         if self.mel.aggregation == "staleness":
             w = staleness_weights(tau, d, gamma=self.mel.staleness_gamma)
@@ -868,13 +900,13 @@ class Orchestrator:
         if eval_fn is not None and eval_batch is None:
             raise ValueError("run_fused needs eval_batch=(x, y) with eval_fn")
         ex, ey = eval_batch if eval_fn is not None else (None, None)
-        xs_d, ys_d, ms_d, tau_d, w_d, lr_d, ex_d, ey_d = to_device(
-            xs, ys, ms, tau, w, np.asarray(self.mel.lr, np.float32), ex, ey)
+        shards_d, tau_d, w_d, lr_d, ex_d, ey_d = to_device(
+            shards, tau, w, np.asarray(self.mel.lr, np.float32), ex, ey)
 
         max_tau = max(int(tau.max()), 1)
         with span("dispatch"):
             self.params, accs = _fused_cycles(
-                self.params, xs_d, ys_d, ms_d, tau_d, w_d, lr_d, ex_d, ey_d,
+                self.params, shards_d, tau_d, w_d, lr_d, ex_d, ey_d,
                 max_tau=max_tau, loss_fn=self.loss_fn, eval_fn=eval_fn,
                 use_pallas=use_pallas, interpret=interpret,
             )
@@ -914,7 +946,6 @@ class Orchestrator:
         if self.mel.aggregation not in ("staleness", "fedavg"):
             raise ValueError(f"unknown aggregation {self.mel.aggregation!r}")
         total = prob.total_samples
-        feat = train.x.shape[1]
         T1, total1, lo1, hi1, valid1 = self._policy_args()
         energy1 = (policy_energy_args(prob)
                    if self.mel.scheme in ENERGY_SCHEMES else None)
@@ -939,14 +970,12 @@ class Orchestrator:
         # (constant) per-cycle total; the split by d happens in the scan
         part = FederatedPartitioner(train, seed=int(self.rng.integers(2**31)))
         with span("stage"):
-            xs = np.zeros((cycles, total, feat), np.float32)
-            ys = np.zeros((cycles, total), np.int32)
+            idx = np.zeros((cycles, total), np.int32)
         for c in range(cycles):
-            with span("stage", bytes=xs[c].nbytes + ys[c].nbytes):
+            with span("stage", bytes=idx[c].nbytes):
                 with span("draw"):
-                    idx = part.draw_indices(total)
-                xs[c] = train.x[idx]
-                ys[c] = train.y[idx]
+                    idx[c] = part.draw_indices(total)
+        shards = SampleIndex(idx, *_device_table(train))
 
         if eval_fn is not None and eval_batch is None:
             raise ValueError("run_fused needs eval_batch=(x, y) with eval_fn")
@@ -958,14 +987,14 @@ class Orchestrator:
                   if is_state_coupled(self.drift)
                   else jnp.zeros((0,), jnp.float32))
         with jax.enable_x64(True):
-            (xs_d, ys_d, c2_d, c1_d, c0_d, T1_d, total1_d, lo1_d, hi1_d,
+            (shards_d, c2_d, c1_d, c0_d, T1_d, total1_d, lo1_d, hi1_d,
              valid1_d, gamma_d, lr_d, *energy_d) = to_device(
-                xs, ys, c2b, c1b, c0b, T1, total1, lo1, hi1, valid1,
+                shards, c2b, c1b, c0b, T1, total1, lo1, hi1, valid1,
                 np.asarray(self.mel.staleness_gamma, np.float64),
                 np.asarray(self.mel.lr, np.float32), *(energy1 or ()))
             with span("dispatch"):
                 (params, _, _), (accs, taus, ds, feas) = _fused_realloc_cycles(
-                    self.params, state0, xs_d, ys_d, c2_d, c1_d, c0_d, T1_d,
+                    self.params, state0, shards_d, c2_d, c1_d, c0_d, T1_d,
                     total1_d, lo1_d, hi1_d, valid1_d,
                     tuple(energy_d) if energy1 is not None else None,
                     gamma_d, lr_d, ex_d, ey_d,

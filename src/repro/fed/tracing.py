@@ -27,9 +27,10 @@ def span(phase: str, **stats) -> jax.profiler.TraceAnnotation:
 
 
 def to_device(*arrays) -> tuple:
-    """``jnp.asarray`` of each of ``arrays`` (None stays None) under a
-    ``fed.h2d`` span whose ``bytes`` stat counts the bytes of the NumPy
-    arrays among them (device arrays are not moved)."""
-    moved = sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))
+    """``jnp.asarray`` of each array leaf of ``arrays`` (pytrees; None
+    stays None) under a ``fed.h2d`` span whose ``bytes`` stat counts the
+    bytes of the NumPy arrays among them (device arrays are not moved)."""
+    leaves = jax.tree_util.tree_leaves(arrays)
+    moved = sum(a.nbytes for a in leaves if isinstance(a, np.ndarray))
     with span("h2d", bytes=int(moved)):
-        return tuple(None if a is None else jnp.asarray(a) for a in arrays)
+        return jax.tree_util.tree_map(jnp.asarray, arrays)
