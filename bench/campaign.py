@@ -16,12 +16,13 @@ the trace can attribute the device's idle time:
 from __future__ import annotations
 
 import dataclasses
+import types
 
 import numpy as np
 
 import jax
 
-from bench import kinds, work
+from bench import kinds, models, work
 from bench.fleet import sub_seed
 
 
@@ -50,14 +51,14 @@ class Runner:
 
     def __init__(self, cfg: dict, traffic: dict, fleet, data, eval_dev, init):
         from repro.core import AllocationProblem, TimeModel
-        from repro.models import mlp
 
         self.kind = kinds.load(traffic)
         self.cfg, self.traffic, self.fleet = cfg, traffic, fleet
         self.train = data
         self.eval = eval_dev
         self.init = init
-        self.mlp = mlp
+        loss, ev = models.load(cfg).program(cfg)
+        self.model = types.SimpleNamespace(loss=loss, eval=ev)
         self.problem = AllocationProblem(
             time_model=TimeModel(c2=fleet.c2, c1=fleet.c1, c0=fleet.c0),
             T=fleet.T, total_samples=fleet.total, d_lower=fleet.d_lo,

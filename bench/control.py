@@ -30,9 +30,9 @@ def half_batch(train):
     """``train_learners`` with the second half of each shard left out."""
     import jax.numpy as jnp
 
-    def broken(start, x, y, m, tau, lr, *, dtype):
+    def broken(start, x, y, m, tau, lr, *, loss, dtype):
         keep = jnp.cumsum(m, axis=1) <= jnp.ceil(m.sum(axis=1, keepdims=True) / 2)
-        return train(start, x, y, m * keep, tau, lr, dtype=dtype)
+        return train(start, x, y, m * keep, tau, lr, loss=loss, dtype=dtype)
 
     return broken
 

@@ -19,9 +19,9 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import check, reference, trace_reduce, work
+from bench import check, models, reference, trace_reduce, work
 from bench.campaign import Runner
-from bench.fleet import build_fleet, init_fn, param_count, sub_seed, synthetic_mnist
+from bench.fleet import build_fleet, sub_seed
 
 ROOT = Path(__file__).resolve().parents[1]
 TRACE_DIR = ROOT / ".bench_trace"
@@ -63,20 +63,19 @@ def enable_cache() -> str:
 
 
 def inputs(cfg: dict, traffic: dict, seed: int) -> types.SimpleNamespace:
-    """The cell's fleet, data and weights generator, and the runner that
-    runs its campaigns on the program."""
+    """The cell's learner model family, fleet, data and weights generator,
+    and the runner that runs its campaigns on the program."""
     import jax.numpy as jnp
     from repro.data.pipeline import Dataset
 
+    model = models.load(cfg)
     fleet = build_fleet(cfg)
-    data = cfg["data"]
-    xtr, ytr, xte, yte = synthetic_mnist(data["train"], data["eval"],
-                                         sub_seed(seed, 0))
+    xtr, ytr, xte, yte = model.data(cfg, sub_seed(seed, 0))
     train = Dataset(xtr, ytr)
     eval_dev = (jnp.asarray(xte), jnp.asarray(yte))
-    init = init_fn(cfg["model"]["layers"])
+    init = model.init(cfg)
     return types.SimpleNamespace(
-        fleet=fleet, train=train, eval=eval_dev, init=init,
+        model=model, fleet=fleet, train=train, eval=eval_dev, init=init,
         runner=Runner(cfg, traffic, fleet, train, eval_dev, init))
 
 
@@ -90,7 +89,8 @@ def reference_of(o, cell_in, cfg: dict, traffic: dict, **kw):
     p0 = jax.tree_util.tree_map(np.asarray, cell_in.init(o.init_seed))
     rp, racc = reference.run_campaign(
         rows, p0, cell_in.train.x, cell_in.train.y, *cell_in.eval,
-        lr=cfg["train"]["lr"], gamma=cfg["train"]["staleness_gamma"], **kw)
+        model=cell_in.model, lr=cfg["train"]["lr"],
+        gamma=cfg["train"]["staleness_gamma"], **kw)
     return rows, p0, rp, racc
 
 
@@ -104,7 +104,6 @@ def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
 
     dev = jax.devices()[0]
     peak = work.peaks(dev.device_kind)
-    layers = cfg["model"]["layers"]
     cell_in = inputs(cfg, traffic, seed)
     runner = cell_in.runner
 
@@ -143,8 +142,8 @@ def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
         jax.profiler.stop_trace()
     in_window = (counter.lowered - lowered0, counter.compiled - compiled0)
     hits = staging_cache_stats()["hits"] - hits0
-    stats = dev.memory_stats() or {}
-    memory_peak = int(stats.get("peak_bytes_in_use", 0))
+    memory_peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                      for d in jax.devices())
 
     secs = [o.seconds for o in outs]
     useful = sum(o.useful for o in outs)
@@ -190,7 +189,8 @@ def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
     if not trace:
         rate = useful / window_s
         values = {"sample_steps_per_s": rate,
-                  "mfu": 100.0 * rate * 6.0 * param_count(layers) / peak["flops_per_s"],
+                  "mfu": (100.0 * rate * cell_in.model.flops_per_sample_step(cfg)
+                          / peak["flops_per_s"]),
                   "setup_s": setup_s}
         result["metrics"] = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                              for m in e2e}
@@ -201,7 +201,8 @@ def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
         device.update(busy_s=trace_reduce.busy(tr, lo, hi) * 1e-9,
                       window_s=(hi - lo) * 1e-9)
         ctx = types.SimpleNamespace(trace=tr, program=traffic["program"],
-                                    campaigns=outs, layers=layers, peak=peak)
+                                    campaigns=outs, model=cell_in.model, cfg=cfg,
+                                    peak=peak)
         metrics = {}
         for m in per_layer:
             value = importlib.import_module(f"bench.metrics.{m['name']}").read(ctx)

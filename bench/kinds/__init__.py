@@ -14,7 +14,9 @@ plain reference expects of it:
              ``bench.reference`` alone and nothing of the program
 
 ``cell`` is the ``bench.campaign.Runner`` of the run: the configuration,
-traffic, fleet, allocation problem, data and the program's MLP module.
+traffic, fleet, allocation problem, data and ``cell.model``, the
+program's ``loss`` and ``eval`` functions for the configuration's learner
+model (``bench/models/``).
 A new kind is a new module here; a new mix of a kind is a data file.
 """
 

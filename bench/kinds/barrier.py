@@ -34,13 +34,13 @@ def build(cell, params, engine_seed: int):
     mel = MELConfig(T=cell.fleet.T, total_samples=cell.fleet.total,
                     lr=train["lr"], scheme=train["scheme"],
                     aggregation=train["aggregation"])
-    return Orchestrator(mel, cell.problem, cell.mlp.loss, params,
+    return Orchestrator(mel, cell.problem, cell.model.loss, params,
                         seed=engine_seed, drift=drift)
 
 
 def run(cell, engine):
     return engine.run_fused(cell.train, int(cell.traffic["cycles"]),
-                            eval_fn=cell.mlp.accuracy, eval_batch=cell.eval,
+                            eval_fn=cell.model.eval, eval_batch=cell.eval,
                             reallocate=bool(cell.traffic["reallocate"]))
 
 

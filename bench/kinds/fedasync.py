@@ -24,14 +24,14 @@ def build(cell, params, engine_seed: int):
     acfg = AsyncConfig(mode="fedasync", staleness_fn="poly", alpha=tr["alpha"],
                        staleness_a=tr["staleness_a"], lr=train["lr"],
                        scheme=train["scheme"], aggregation=train["aggregation"])
-    return AsyncFedEngine(acfg, cell.problem, cell.mlp.loss, params,
+    return AsyncFedEngine(acfg, cell.problem, cell.model.loss, params,
                           seed=engine_seed)
 
 
 def run(cell, engine):
     return engine.run_events(cell.train,
                              float(cell.traffic["horizon_cycles"]) * cell.fleet.T,
-                             eval_fn=cell.mlp.accuracy, eval_batch=cell.eval)
+                             eval_fn=cell.model.eval, eval_batch=cell.eval)
 
 
 def schedule(fleet, traffic: dict, engine_seed: int, n_train: int) -> list:

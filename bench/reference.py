@@ -18,8 +18,9 @@ staleness, mixing weights) is built from these pieces by the kind's
   partition seed is the first ``integers(2**31)`` of the engine's
   ``default_rng(seed)``.
 * Training: full-batch gradient descent on each learner's own shard for
-  tau_k steps, then the staleness-weighted average (barrier) or the
-  FedAsync mix ``(1 - a s(v)) w + a s(v) w_k`` with s(v) = (1 + v)^-0.5.
+  tau_k steps, on the learner model family's plain ``loss``
+  (``bench/models/``), then the staleness-weighted average (barrier) or
+  the FedAsync mix ``(1 - a s(v)) w + a s(v) w_k`` with s(v) = (1 + v)^-0.5.
 
 ``dtype`` float32 runs every matmul at HIGHEST precision (the
 reference); bfloat16 runs the same arithmetic in bfloat16 (the control,
@@ -175,35 +176,17 @@ class Draws:
 # ---------------------------------------------------------------------------
 
 
-def _dot(a, b):
-    if a.dtype == jnp.float32:
-        return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
-    return jnp.matmul(a, b)
-
-
-def forward(params, x):
-    h = x
-    for i, layer in enumerate(params):
-        h = _dot(h, layer["w"]) + layer["b"]
-        if i < len(params) - 1:
-            h = jnp.maximum(h, 0)
-    return h
-
-
-def loss(params, x, y, m):
-    logits = forward(params, x)
-    logp = jax.nn.log_softmax(logits)
-    nll = -jnp.take_along_axis(logp, y[:, None], axis=1)[:, 0]
-    return (nll * m).sum() / jnp.maximum(m.sum(), 1)
-
-
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def train_learners(start, x, y, m, tau, lr, *, dtype):
+@functools.partial(jax.jit, static_argnames=("loss", "dtype"))
+def train_learners(start, x, y, m, tau, lr, *, loss, dtype):
     """tau_k full-batch GD steps of each learner from its own start
-    params (leading learner axis), on rows where m = 1."""
+    params (leading learner axis) on the family's ``loss``, on rows where
+    m = 1. Floating inputs are cast to ``dtype``; integer ones (token
+    ids) are not."""
     cast = lambda t: jax.tree_util.tree_map(lambda a: a.astype(dtype), t)
-    start, x, m = cast(start), x.astype(dtype), m.astype(dtype)
-    lr = lr.astype(dtype)
+    start = cast(start)
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        x = x.astype(dtype)
+    m, lr = m.astype(dtype), lr.astype(dtype)
 
     def one(p, xk, yk, mk, tk):
         g = jax.grad(loss)
@@ -227,10 +210,10 @@ def mix(server, locals_, keep, w, *, dtype):
         server, locals_)
 
 
-@jax.jit
-def accuracy(params, x, y):
-    logits = forward(jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params), x)
-    return jnp.mean(jnp.argmax(logits, axis=-1) == y)
+@functools.partial(jax.jit, static_argnames=("accuracy",))
+def evaluate(params, x, y, *, accuracy):
+    """The family's ``accuracy`` of ``params``, read in float32."""
+    return accuracy(jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params), x, y)
 
 
 def _pad_rows(d_max: int) -> int:
@@ -238,22 +221,22 @@ def _pad_rows(d_max: int) -> int:
 
 
 def run_campaign(schedule, init_params, train_x, train_y, eval_x, eval_y, *,
-                 lr: float, gamma: float, dtype=jnp.float32,
+                 model, lr: float, gamma: float, dtype=jnp.float32,
                  train_override=None):
-    """Train one campaign along ``schedule``; returns ``(params, accs)``.
+    """Train one campaign along ``schedule`` with the loss and accuracy of
+    the family ``model`` (``bench/models/``); returns ``(params, accs)``.
     ``train_override`` replaces ``train_learners`` (fault readings)."""
     train = train_override or train_learners
     k_all = max(int(np.max(e["learners"])) for e in schedule) + 1
     server = jax.tree_util.tree_map(lambda a: jnp.asarray(a, dtype), init_params)
     dispatched = [server] * k_all
     rows = _pad_rows(max(int(np.max(e["d"])) for e in schedule))
-    feat = train_x.shape[1]
     lr_j = jnp.float32(lr)
     accs = []
     for e in schedule:
         ks = e["learners"]
-        x = np.zeros((len(ks), rows, feat), np.float32)
-        y = np.zeros((len(ks), rows), np.int32)
+        x = np.zeros((len(ks), rows, *train_x.shape[1:]), train_x.dtype)
+        y = np.zeros((len(ks), rows, *train_y.shape[1:]), train_y.dtype)
         m = np.zeros((len(ks), rows), np.float32)
         for j, idx in enumerate(e["idx"]):
             x[j, :len(idx)], y[j, :len(idx)], m[j, :len(idx)] = (
@@ -262,7 +245,7 @@ def run_campaign(schedule, init_params, train_x, train_y, eval_x, eval_y, *,
                                        *[dispatched[k] for k in ks])
         tau = jnp.asarray(e["tau"], jnp.int32)
         locals_ = train(start, jnp.asarray(x), jnp.asarray(y), jnp.asarray(m),
-                        tau, lr_j, dtype=dtype)
+                        tau, lr_j, loss=model.loss, dtype=dtype)
         if "keep" in e:             # FedAsync: one upload mixed into the server
             keep, w = e["keep"], e["weights"]
         else:                       # barrier: staleness-weighted average
@@ -275,5 +258,5 @@ def run_campaign(schedule, init_params, train_x, train_y, eval_x, eval_y, *,
             dispatched[int(ks[0])] = server
         else:
             dispatched = [server] * k_all
-        accs.append(float(accuracy(server, eval_x, eval_y)))
+        accs.append(float(evaluate(server, eval_x, eval_y, accuracy=model.accuracy)))
     return jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32), server), accs

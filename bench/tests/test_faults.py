@@ -20,9 +20,6 @@ The control (the reference in bfloat16 in the program's place) must fail
 the same limits.
 """
 
-import json
-import time
-
 import pytest
 
 import jax
@@ -30,36 +27,9 @@ import jax.numpy as jnp
 
 import tiny
 from bench import campaign, check, harness
+from tiny import CELLS, limits, run_tiny
 
-CELLS = {"barrier": "mel_mnist_k20.barrier",
-         "fedasync": "mel_mnist_k20.fedasync",
-         "realloc_drift": "mel_mnist_k20.realloc_drift"}
-SPEC = json.loads((tiny.BENCH.parent / "BENCHMARK.json").read_text())
-
-
-def limits(mix):
-    return json.loads((tiny.BENCH / "checks" / f"{CELLS[mix]}.json").read_text())
-
-
-def run_tiny(mix, seed=2**31 + 99, seconds=0.0):
-    real_peaks = harness.work.peaks
-    harness.work.peaks = lambda kind: real_peaks("TPU v5 lite")
-    try:
-        return harness.run(tiny.config(), tiny.traffic(mix), limits(mix),
-                           seed=seed, seconds=seconds, trace=False,
-                           t_start=time.perf_counter(), e2e=SPEC["end_to_end"],
-                           per_layer=[])
-    finally:
-        harness.work.peaks = real_peaks
-
-
-@pytest.fixture(autouse=True)
-def fresh_programs(monkeypatch):
-    """Programs traced with a fault must not outlive the test."""
-    monkeypatch.setattr(harness, "enable_cache", lambda: None)
-    jax.clear_caches()
-    yield
-    jax.clear_caches()
+pytestmark = pytest.mark.usefixtures("fresh_programs")
 
 
 def _halve(m):

@@ -1,5 +1,6 @@
 import pytest
 
+import tiny
 from bench import work
 
 
@@ -16,9 +17,13 @@ def test_unknown_kind_raises(kind):
 
 
 def test_useful_work_counts_tau_times_d():
+    import json
+
     import numpy as np
+
+    from bench import models
 
     rows = [{"tau": np.array([3, 2]), "d": np.array([10, 5])}] * 2
     assert work.sample_steps(rows) == 2 * (30 + 10)
-    layers = [784, 300, 124, 60, 10]
-    assert work.flops(layers, 1) == 6 * 280934
+    cfg = json.loads((tiny.BENCH / "configs" / "mel_mnist_k20.json").read_text())
+    assert work.flops(models.load(cfg), cfg, 1) == 6 * 280934

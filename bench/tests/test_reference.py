@@ -8,8 +8,8 @@ import pytest
 import jax
 
 import tiny
-from bench import check, kinds, reference
-from bench.fleet import build_fleet, init_fn, synthetic_mnist
+from bench import check, kinds, models, reference
+from bench.fleet import build_fleet
 
 SEED = 2**31 + 7
 
@@ -19,15 +19,15 @@ def data():
     from repro.data.pipeline import Dataset
 
     cfg = tiny.config()
-    xtr, ytr, xte, yte = synthetic_mnist(cfg["data"]["train"], cfg["data"]["eval"], 3)
+    xtr, ytr, xte, yte = models.load(cfg).data(cfg, 3)
     return cfg, Dataset(xtr, ytr), (xte, yte)
 
 
 def _eager(cfg, traffic, train, ev, engine_seed, p0):
     """The program's eager (host-loop) twin of the cell's timed path."""
     from repro.core import AllocationProblem, CapacityDrift, TimeModel
-    from repro.models import mlp
 
+    loss, accuracy = models.load(cfg).program(cfg)
     f = build_fleet(cfg)
     prob = AllocationProblem(TimeModel(f.c2, f.c1, f.c0), f.T, f.total, f.d_lo, f.d_hi)
     tr = cfg["train"]
@@ -39,8 +39,8 @@ def _eager(cfg, traffic, train, ev, engine_seed, p0):
             clock_jitter=d["clock_jitter"], fading_sigma_db=d["fading_sigma_db"],
             fading_clip_db=d["fading_clip_db"], seed=d["seed"])
         eng = Orchestrator(MELConfig(T=f.T, total_samples=f.total, lr=tr["lr"]),
-                           prob, mlp.loss, p0, seed=engine_seed, drift=drift)
-        acc = jax.jit(mlp.accuracy)
+                           prob, loss, p0, seed=engine_seed, drift=drift)
+        acc = jax.jit(accuracy)
         hist = eng.run(train, traffic["cycles"], reallocate=traffic["reallocate"],
                        eval_fn=lambda p: acc(p, *ev))
     else:
@@ -48,10 +48,10 @@ def _eager(cfg, traffic, train, ev, engine_seed, p0):
 
         eng = AsyncFedEngine(AsyncConfig(mode="fedasync", alpha=traffic["alpha"],
                                          staleness_a=traffic["staleness_a"],
-                                         lr=tr["lr"]), prob, mlp.loss, p0,
+                                         lr=tr["lr"]), prob, loss, p0,
                              seed=engine_seed)
         hist = eng.run(train, traffic["horizon_cycles"] * f.T,
-                       eval_fn=mlp.accuracy, eval_batch=ev)
+                       eval_fn=accuracy, eval_batch=ev)
     return eng.params, hist
 
 
@@ -61,12 +61,12 @@ def test_reference_matches_eager_engine(data, mix):
 
     cfg, train, ev = data
     traffic = tiny.traffic(mix)
-    layers = cfg["model"]["layers"]
-    p0 = jax.tree_util.tree_map(np.asarray, init_fn(layers)(11))
+    model = models.load(cfg)
+    p0 = jax.tree_util.tree_map(np.asarray, model.init(cfg)(11))
     params, hist = _eager(cfg, traffic, train, ev, 5, p0)
     rows = kinds.load(traffic).schedule(build_fleet(cfg), traffic, 5, train.size)
     assert check.sched_mismatch(_rows(hist), rows) == 0
-    rp, racc = reference.run_campaign(rows, p0, train.x, train.y, *ev,
+    rp, racc = reference.run_campaign(rows, p0, train.x, train.y, *ev, model=model,
                                       lr=cfg["train"]["lr"], gamma=1.0)
     gap, dev = check.param_numbers(p0, params, rp)
     assert dev < 1e-5 and gap < 1e-5
@@ -117,20 +117,21 @@ def test_drift_without_reallocation_is_refused():
 
 @pytest.mark.parametrize("name", ["mel_mnist_k20", "fedavg_2nn_k100"])
 def test_fleet_copy_is_the_programs_builder(name):
-    """``bench/fleet.py`` copies the program's fleet builders so that the
-    yardstick does not move with the program; today the copy and the
-    originals (``indoor_80211_profile``, ``mlp_cost``, ``TimeModel.build``)
-    give the same Eq. 5 coefficients."""
+    """``bench/fleet.py`` and ``bench/models/mlp.py`` copy the program's
+    fleet builders so that the yardstick does not move with the program;
+    today the copy and the originals (``indoor_80211_profile``,
+    ``mlp_cost``, ``TimeModel.build``) give the same Eq. 5 coefficients."""
     import json
 
     from repro.core import TimeModel, indoor_80211_profile, mlp_cost
 
     cfg = json.loads((tiny.BENCH / "configs" / f"{name}.json").read_text())
     f = build_fleet(cfg)
-    cost = mlp_cost(cfg["model"]["layers"])
+    cost = mlp_cost(tiny.WIDTHS[name])
     tm = TimeModel.build(indoor_80211_profile(cfg["fleet"]["learners"],
                                               seed=cfg["fleet"]["seed"]),
                          model_complexity_flops=cost.flops_per_sample,
-                         model_size_bits=cost.model_bits)
+                         model_size_bits=cost.model_bits,
+                         features_per_sample=tiny.WIDTHS[name][0])
     for ours, theirs in ((f.c2, tm.c2), (f.c1, tm.c1), (f.c0, tm.c0)):
         np.testing.assert_allclose(ours, theirs, rtol=1e-12, atol=0)

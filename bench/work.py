@@ -2,9 +2,10 @@
 
 Useful work counts what the algorithm requires, whatever runs it: every
 learner's tau_k full-batch gradient steps over its d_k samples, so
-sum(tau_k d_k) sample-steps per aggregation, at 6 FLOPs per parameter
-per sample-step (forward 2, backward 4). Padded rows and steps past
-tau_k are not counted, and neither is evaluation.
+sum(tau_k d_k) sample-steps per aggregation, at the learner model's
+FLOPs per sample-step (its family's ``flops_per_sample_step``, in
+``bench/models/``). Padded rows and steps past tau_k are not counted,
+and neither is evaluation.
 """
 
 from __future__ import annotations
@@ -12,10 +13,7 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-from bench.fleet import param_count
-
 PEAKS = Path(__file__).with_name("peaks.json")
-BYTES_F32 = 4
 
 
 class UnknownDevice(KeyError):
@@ -37,22 +35,13 @@ def sample_steps(rows) -> int:
     return sum(int((r["tau"] * r["d"]).sum()) for r in rows)
 
 
-def flops(layers, steps: int) -> float:
-    return 6.0 * param_count(layers) * steps
+def flops(model, cfg: dict, steps: int) -> float:
+    """FLOPs of ``steps`` sample-steps of the family ``model``."""
+    return model.flops_per_sample_step(cfg) * steps
 
 
-def least_bytes(layers, rows) -> float:
-    """HBM traffic no implementation can avoid: each learner reads its
-    shard (784 f32 features and an int32 label per sample) and its start
-    params once, and writes its trained params once, per round."""
-    p = param_count(layers) * BYTES_F32
-    per_sample = (layers[0] + 1) * BYTES_F32
-    return float(sum(int(r["d"].sum()) * per_sample + 2 * p * len(r["d"])
-                     for r in rows))
-
-
-def least_seconds(layers, rows, peak: dict) -> float:
+def least_seconds(model, cfg: dict, rows, peak: dict) -> float:
     """The larger of the useful FLOPs at peak FLOP/s and the unavoidable
-    bytes at peak HBM bandwidth."""
-    return max(flops(layers, sample_steps(rows)) / peak["flops_per_s"],
-               least_bytes(layers, rows) / peak["hbm_bytes_per_s"])
+    bytes (the family's ``least_bytes``) at peak HBM bandwidth."""
+    return max(flops(model, cfg, sample_steps(rows)) / peak["flops_per_s"],
+               model.least_bytes(cfg, rows) / peak["hbm_bytes_per_s"])
