@@ -27,11 +27,12 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
 def half_batch(train):
-    """``train_learners`` with the second half of each shard left out."""
+    """``train_learners`` with the second half of each shard left out (the
+    rows are the mask's last axis, with or without a learner axis)."""
     import jax.numpy as jnp
 
     def broken(start, x, y, m, tau, lr, *, loss, dtype):
-        keep = jnp.cumsum(m, axis=1) <= jnp.ceil(m.sum(axis=1, keepdims=True) / 2)
+        keep = jnp.cumsum(m, axis=-1) <= jnp.ceil(m.sum(axis=-1, keepdims=True) / 2)
         return train(start, x, y, m * keep, tau, lr, loss=loss, dtype=dtype)
 
     return broken

@@ -94,6 +94,22 @@ def reference_of(o, cell_in, cfg: dict, traffic: dict, **kw):
     return rows, p0, rp, racc
 
 
+def _offload(outs: list, moving: list | None) -> list:
+    """Moves finished campaigns' params off the device, one campaign
+    behind: the params whose host copy started at the previous call land
+    as NumPy, which frees their device buffers, and the copy of those
+    still on the device starts (at the first call every finished
+    campaign's, then the newest's). Returns the campaigns being copied."""
+    import jax
+
+    for o in moving or ():
+        o.params = jax.tree_util.tree_map(np.asarray, o.params)
+    moving = outs[-1:] if moving is not None else list(outs)
+    for leaf in jax.tree_util.tree_leaves([o.params for o in moving]):
+        leaf.copy_to_host_async()
+    return moving
+
+
 def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
         seconds: float, trace: bool, t_start: float, e2e: list, per_layer: list) -> dict:
     import jax
@@ -122,6 +138,8 @@ def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
         opts.python_tracer_level = 0
         jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
     outs, attempted, failed = [], 0, 0
+    budget = work.memory_budget()
+    held, moving = 0, None
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.window"):
         while True:
@@ -135,6 +153,9 @@ def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
             else:
                 o.seconds = time.perf_counter() - t
                 outs.append(o)
+                held += work.tree_bytes(o.params)
+                if moving is not None or held > budget:
+                    moving = _offload(outs, moving)
             if time.perf_counter() - t0 >= seconds:
                 break
     window_s = time.perf_counter() - t0
@@ -154,6 +175,8 @@ def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
         out=sys.stdout)
     log(f"compiles in window: {in_window[0]} lowered, {in_window[1]} compiled; "
         f"staging-cache hits in window: {hits}", out=sys.stdout)
+    log(f"memory budget: {budget / 1e9:.4g} GB; finished campaigns' params "
+        f"{held / 1e9:.4g} GB, {'moved to the host past it' if moving else 'kept on the device'}")
 
     # the check: free the program's state, then the reference
     rng = np.random.default_rng(sub_seed(seed, 7))
@@ -174,7 +197,8 @@ def run(cfg: dict, traffic: dict, limits: dict, *, seed: int,
         readings.append(check.numbers(o, rows, rp, racc, p0))
         log(f"checked campaign {o.index}: " + ", ".join(
             f"{k} {v:.6g}" for k, v in readings[-1].items()))
-    log(f"reference: {len(checked)} campaigns in {time.perf_counter() - t_ref:.3f} s")
+    log(f"reference: {len(checked)} campaigns in {time.perf_counter() - t_ref:.3f} s "
+        f"(memory budget {work.memory_budget() / 1e9:.4g} GB)")
     # the window's own invariants: every campaign completes, none replays a
     # staged schedule, nothing is lowered or compiled
     window_numbers = {"failed_campaigns": failed, "staging_hits": hits,

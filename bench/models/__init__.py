@@ -28,6 +28,16 @@ configuration ``cfg``:
              right. Matmuls run at HIGHEST precision on float32 params,
              and in bfloat16 where the control casts the params to it.
              This part imports nothing of the program.
+             ``loss`` gets one learner's block, unbatched: ``x`` and ``y``
+             of shape ``(rows, ...)`` and the mask ``m`` of shape
+             ``(rows,)``, 1 on the learner's samples and 0 on the padding.
+             The reference maps it over a group of learners with ``vmap``,
+             or, where one learner is all that fits in the memory budget
+             (``bench/reference.py``), calls it on that learner alone. It
+             must stay the masked mean over the rows where m = 1, and it
+             may skip rows whose mask is 0, such as a block of rows behind
+             a ``lax.cond``: under ``vmap`` the cond becomes a select and
+             computes both sides, on its own it skips.
 
 A new family is a new module here, and its configuration a data file.
 """

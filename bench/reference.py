@@ -21,6 +21,10 @@ staleness, mixing weights) is built from these pieces by the kind's
   tau_k steps, on the learner model family's plain ``loss``
   (``bench/models/``), then the staleness-weighted average (barrier) or
   the FedAsync mix ``(1 - a s(v)) w + a s(v) w_k`` with s(v) = (1 + v)^-0.5.
+  Where the K learners of an aggregation fit in ``work.memory_budget()``
+  at three copies of the params each (start, carry, gradient), they train
+  in one ``vmap``; otherwise in groups of the most that fit, at least one,
+  whose trained params are summed into one accumulator as each group ends.
 
 ``dtype`` float32 runs every matmul at HIGHEST precision (the
 reference); bfloat16 runs the same arithmetic in bfloat16 (the control,
@@ -35,6 +39,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from bench import work
 
 # ---------------------------------------------------------------------------
 # allocation: KKT water-filling + SAI
@@ -180,8 +186,10 @@ class Draws:
 def train_learners(start, x, y, m, tau, lr, *, loss, dtype):
     """tau_k full-batch GD steps of each learner from its own start
     params (leading learner axis) on the family's ``loss``, on rows where
-    m = 1. Floating inputs are cast to ``dtype``; integer ones (token
-    ids) are not."""
+    m = 1. A scalar ``tau`` is one learner, with no learner axis on any
+    operand: traced without ``vmap``, so that a family's ``lax.cond``
+    stays a branch. Floating inputs are cast to ``dtype``; integer ones
+    (token ids) are not."""
     cast = lambda t: jax.tree_util.tree_map(lambda a: a.astype(dtype), t)
     start = cast(start)
     if jnp.issubdtype(x.dtype, jnp.floating):
@@ -198,15 +206,18 @@ def train_learners(start, x, y, m, tau, lr, *, loss, dtype):
 
         return jax.lax.fori_loop(0, jnp.max(tau), step, p)
 
+    if jnp.ndim(tau) == 0:
+        return one(start, x, y, m, tau)
     return jax.vmap(one)(start, x, y, m, tau)
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
 def mix(server, locals_, keep, w, *, dtype):
-    """keep * server + sum_k w_k local_k."""
+    """keep * server + sum_k w_k local_k; a scalar ``w`` weighs one local
+    with no learner axis."""
     return jax.tree_util.tree_map(
         lambda s, l: (keep.astype(dtype) * s.astype(dtype)
-                      + jnp.tensordot(w.astype(dtype), l.astype(dtype), 1)),
+                      + jnp.tensordot(w.astype(dtype), l.astype(dtype), w.ndim)),
         server, locals_)
 
 
@@ -214,6 +225,10 @@ def mix(server, locals_, keep, w, *, dtype):
 def evaluate(params, x, y, *, accuracy):
     """The family's ``accuracy`` of ``params``, read in float32."""
     return accuracy(jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params), x, y)
+
+
+def _stack(trees):
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *trees)
 
 
 def _pad_rows(d_max: int) -> int:
@@ -225,13 +240,16 @@ def run_campaign(schedule, init_params, train_x, train_y, eval_x, eval_y, *,
                  train_override=None):
     """Train one campaign along ``schedule`` with the loss and accuracy of
     the family ``model`` (``bench/models/``); returns ``(params, accs)``.
-    ``train_override`` replaces ``train_learners`` (fault readings)."""
+    ``train_override`` replaces ``train_learners`` (fault readings), for
+    each group of learners."""
     train = train_override or train_learners
     k_all = max(int(np.max(e["learners"])) for e in schedule) + 1
     server = jax.tree_util.tree_map(lambda a: jnp.asarray(a, dtype), init_params)
     dispatched = [server] * k_all
     rows = _pad_rows(max(int(np.max(e["d"])) for e in schedule))
     lr_j = jnp.float32(lr)
+    # learners whose start params, carries and gradients fit at once
+    fit = work.memory_budget() / (3 * work.tree_bytes(server))
     accs = []
     for e in schedule:
         ks = e["learners"]
@@ -241,22 +259,37 @@ def run_campaign(schedule, init_params, train_x, train_y, eval_x, eval_y, *,
         for j, idx in enumerate(e["idx"]):
             x[j, :len(idx)], y[j, :len(idx)], m[j, :len(idx)] = (
                 train_x[idx], train_y[idx], 1.0)
-        start = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
-                                       *[dispatched[k] for k in ks])
-        tau = jnp.asarray(e["tau"], jnp.int32)
-        locals_ = train(start, jnp.asarray(x), jnp.asarray(y), jnp.asarray(m),
-                        tau, lr_j, loss=model.loss, dtype=dtype)
         if "keep" in e:             # FedAsync: one upload mixed into the server
             keep, w = e["keep"], e["weights"]
         else:                       # barrier: staleness-weighted average
             t = np.asarray(e["tau"], float)
             w = np.asarray(e["d"], float) / (1.0 + gamma * (t.max() - t))
             keep, w = 0.0, w / w.sum()
-        server = mix(server, locals_, jnp.float32(keep),
-                     jnp.asarray(w, jnp.float32), dtype=dtype)
+        if len(ks) <= fit:          # one vmap over every learner
+            start = _stack([dispatched[k] for k in ks])
+            tau = jnp.asarray(e["tau"], jnp.int32)
+            locals_ = train(start, jnp.asarray(x), jnp.asarray(y), jnp.asarray(m),
+                            tau, lr_j, loss=model.loss, dtype=dtype)
+            server = mix(server, locals_, jnp.float32(keep),
+                         jnp.asarray(w, jnp.float32), dtype=dtype)
+        else:                       # groups of g, summed as each ends
+            g, keep_j, tau = max(1, int(fit)), jnp.float32(keep), np.asarray(e["tau"])
+            for lo in range(0, len(ks), g):
+                if len(ks) - lo == 1 or g == 1:     # one learner: no learner axis
+                    part, start = lo, dispatched[ks[lo]]
+                else:
+                    part = slice(lo, lo + g)
+                    start = _stack([dispatched[k] for k in ks[part]])
+                local = train(start, jnp.asarray(x[part]), jnp.asarray(y[part]),
+                              jnp.asarray(m[part]), jnp.asarray(tau[part], jnp.int32),
+                              lr_j, loss=model.loss, dtype=dtype)
+                server = mix(server, local, keep_j, jnp.asarray(w[part], jnp.float32),
+                             dtype=dtype)
+                keep_j = jnp.float32(1.0)
         if "keep" in e:
             dispatched[int(ks[0])] = server
         else:
             dispatched = [server] * k_all
         accs.append(float(evaluate(server, eval_x, eval_y, accuracy=model.accuracy)))
     return jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32), server), accs
+

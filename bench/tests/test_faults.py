@@ -17,7 +17,11 @@ schedule (a staging-cache hit), or a program lowered inside the window.
 
 The cells run on one chip, so no exchange between chips can be left out.
 The control (the reference in bfloat16 in the program's place) must fail
-the same limits.
+the same limits, and so must ``bench/control.py``'s ``half_batch``.
+
+Each fault and reading fails also at a memory budget of 0, where the
+reference trains one learner at a time and the window moves each
+finished campaign's params to the host.
 """
 
 import pytest
@@ -26,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 import tiny
-from bench import campaign, check, harness
+from bench import campaign, check, control, harness, reference, work
 from tiny import CELLS, limits, run_tiny
 
 pytestmark = pytest.mark.usefixtures("fresh_programs")
@@ -87,6 +91,15 @@ def test_fault_is_not_correct(monkeypatch, mix, fault):
     assert not res["correct"], res["checks"]
 
 
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch", "altered"])
+@pytest.mark.parametrize("mix", sorted(CELLS))
+def test_fault_is_not_correct_past_the_budget(monkeypatch, mix, fault):
+    monkeypatch.setattr(work, "memory_budget", lambda: 0)
+    plant(monkeypatch, mix, fault)
+    res = run_tiny(mix)
+    assert not res["correct"], res["checks"]
+
+
 def test_altered_allocation_is_not_correct(monkeypatch):
     from repro.core import Allocation
     from repro.fed import orchestrator
@@ -105,18 +118,31 @@ def test_altered_allocation_is_not_correct(monkeypatch):
     assert not res["correct"]
 
 
-@pytest.mark.parametrize("mix", sorted(CELLS))
-def test_control_is_not_correct(mix):
-    """The reference computed in bfloat16, put in the program's place."""
+def _reading_is_not_correct(mix, **kw):
+    """The reference run with ``kw``, put in the program's place."""
     cfg, traffic = tiny.config(), tiny.traffic(mix)
     cell_in = harness.inputs(cfg, traffic, 5)
     o = cell_in.runner.run(5, 0)
     rows, p0, rp, racc = harness.reference_of(o, cell_in, cfg, traffic)
-    _, _, cp, cacc = harness.reference_of(o, cell_in, cfg, traffic,
-                                          dtype=jnp.bfloat16)
+    _, _, cp, cacc = harness.reference_of(o, cell_in, cfg, traffic, **kw)
     o.params, o.accs = cp, cacc
     ok, shown = check.verdict(check.numbers(o, rows, rp, racc, p0), limits(mix))
     assert not ok, shown
+
+
+@pytest.mark.parametrize("mix", sorted(CELLS))
+def test_control_is_not_correct(mix):
+    """The reference computed in bfloat16, put in the program's place."""
+    _reading_is_not_correct(mix, dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("reading", ["control", "half_batch"])
+@pytest.mark.parametrize("mix", sorted(CELLS))
+def test_control_reading_is_not_correct_past_the_budget(monkeypatch, mix, reading):
+    monkeypatch.setattr(work, "memory_budget", lambda: 0)
+    _reading_is_not_correct(mix, **(
+        {"dtype": jnp.bfloat16} if reading == "control"
+        else {"train_override": control.half_batch(reference.train_learners)}))
 
 
 def test_raising_campaign_is_not_correct(monkeypatch):
